@@ -17,9 +17,14 @@ Gate (check_matvecs --distributed-baseline): matvec counts exact (zero slack),
 collectives-per-matvec ≤ the committed baseline, ring ``all_gather`` == 0 and
 SGD materialised-feature traces == 0 structurally on the fresh run.
 
-CPU container note: the ring's *wall-clock* win needs real interconnect —
-on a host-platform mesh the ppermute is a memcpy, so ``us_per_mv`` here is
-informational (schedule structure, not speed, is what CI gates).
+CPU-only structure gate: the worker forces ``JAX_PLATFORMS=cpu`` and four
+host-platform devices, so on a TPU machine it would still measure the CPU
+(and a child that wanted the chip would find it held by the parent). Do not
+run it on the chip machine; the four-chip path on real chips is
+``python chip_smoke.py --chips 4``. The ring's *wall-clock* win needs real
+interconnect — on a host-platform mesh the ppermute is a memcpy, so
+``us_per_mv`` here is informational (schedule structure, not speed, is what
+CI gates).
 """
 from __future__ import annotations
 

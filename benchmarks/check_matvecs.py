@@ -1,4 +1,4 @@
-"""Matvec/iteration-count regression gate (CI step): run bench_solvers and
+"""Matvec/iteration-count regression gate (CI step, CPU only): run bench_solvers and
 bench_mll in smoke mode and fail if any counted total exceeds its committed
 baseline (``results/BENCH_bench_solvers.json``, ``results/BENCH_bench_mll.json``).
 
@@ -71,6 +71,11 @@ Usage:
 
 ``--slack`` tolerates small cross-platform jitter (fp32 reduction order):
 measured > ceil(baseline · (1 + slack)) fails.
+
+Run it with ``JAX_PLATFORMS=cpu`` and not on the chip machine: its baselines
+are XLA-CPU counts and timings, and its distributed gate starts a child
+process on forced host-platform devices, which cannot share a chip with a
+parent that already holds it. The chip run is ``python chip_smoke.py``.
 """
 from __future__ import annotations
 

@@ -17,6 +17,8 @@ import os
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from .common import Report
 
 BENCHES = [
@@ -59,6 +61,7 @@ def main(argv=None):
         help="directory for the per-bench BENCH_<name>.json files",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     names = args.only.split(",") if args.only else BENCHES
     report = Report()

@@ -16,6 +16,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+#: fp32 means fp32 on every backend: TPU dots default to reduced-precision
+#: bf16 passes for float32 operands, CPU dots are full fp32 either way.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 SE = "se"
 MATERN12 = "matern12"
 MATERN32 = "matern32"
@@ -71,7 +75,7 @@ def _sqdist(x: jax.Array, z: jax.Array) -> jax.Array:
     """Squared Euclidean distances via the matmul identity; clamped at 0."""
     xn = jnp.sum(x * x, axis=-1)[:, None]
     zn = jnp.sum(z * z, axis=-1)[None, :]
-    d2 = xn + zn - 2.0 * (x @ z.T)
+    d2 = xn + zn - 2.0 * jnp.matmul(x, z.T, precision=HIGHEST)
     return jnp.maximum(d2, 0.0)
 
 
@@ -171,7 +175,7 @@ def matvec(
     rows = xp.reshape(n // row_chunk + (pad > 0), row_chunk, x.shape[1])
 
     def chunk(xc):
-        return gram(params, xc, z_) @ v2
+        return jnp.matmul(gram(params, xc, z_), v2, precision=HIGHEST)
 
     out = jax.lax.map(chunk, rows).reshape(-1, v2.shape[1])[:n]
     if jitter is not None and z is None:

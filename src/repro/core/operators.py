@@ -65,7 +65,6 @@ from typing import TYPE_CHECKING, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..kernels.ops import gram_mv, gram_rows_matvec, gram_rows_pair
 from .kernels_fn import KernelParams, gram, gram_diag, matvec
@@ -599,6 +598,20 @@ class LatentKroneckerOp(_InstrumentedOp):
 COMM_STRATEGIES = ("gather", "ring", "auto")
 
 
+def shard_map_over(mesh: Mesh, body, in_specs, out_specs):
+    """``jax.shard_map(body)`` over ``mesh`` with each spec written as a tuple
+    of ``PartitionSpec`` entries (``(axes, None)`` → ``P(axes, None)``).
+
+    The varying-manual-axes check is off: every body ends in an explicit
+    collective (psum / all_gather / ppermute) or returns a sharded block whose
+    out spec says so."""
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=tuple(P(*s) for s in in_specs),
+        out_specs=P(*out_specs), check_vma=False,
+    )
+
+
 def _psum_row_gather(x_local, idx, axes):
     """``x_full[idx]`` without replicating x: every device contributes the
     ``idx`` rows that live in its shard (others zeroed) and a psum reduces.
@@ -739,20 +752,37 @@ class ShardedGram(_InstrumentedOp):
         panel_bytes = self.x.shape[0] * self.x.shape[1] * self.x.dtype.itemsize
         return "ring" if panel_bytes > self.comm_budget_bytes else "gather"
 
+    def _panel_map(self, body, in_specs, out_specs, *args):
+        """shard_map ``body(x_local, x_all, *args)`` with ``x_all`` the
+        replicated (n, d) input panel: the cached ``x_full`` when gathered
+        once per solve, else an ``all_gather`` of the shards on every call
+        (the gather strategy). ``in_specs`` cover ``args`` only."""
+        axes = self.data_axes
+        if self.x_full is not None:
+            return shard_map_over(
+                self.mesh, body, ((axes, None), (None, None), *in_specs), out_specs
+            )(self.x, self.x_full, *args)
+
+        def gathered(x_local, *rest):
+            x_all = jax.lax.all_gather(x_local, axes, tiled=True)
+            return body(x_local, x_all, *rest)
+
+        return shard_map_over(
+            self.mesh, gathered, ((axes, None), *in_specs), out_specs
+        )(self.x, *args)
+
     def _gather_rows(self, idx: jax.Array) -> jax.Array:
-        """x[idx] as a replicated (|idx|, d) panel without replicating x: a
-        masked psum over the canonical block-row layout (ring strategy), the
-        cached ``x_full`` (gather_once), or a plain take (gather, where the
-        partitioner stages its own gather of the small panel)."""
+        """x[idx] as a replicated (|idx|, d) panel: the cached ``x_full`` when
+        gathered once, else a masked psum over the canonical block-row layout
+        — O(|idx|·d) bytes on the wire under either comm strategy, and no
+        global indexing of the sharded x (which explicit-sharding meshes
+        refuse without an output sharding)."""
         if self.x_full is not None:
             return jnp.take(self.x_full, idx, axis=0)
-        if self._resolve_comm() != "ring":
-            return jnp.take(self.x, idx, axis=0)
         axes = self.data_axes
-        return shard_map(
-            lambda x_local, idx_rep: _psum_row_gather(x_local, idx_rep, axes),
-            mesh=self.mesh, in_specs=(P(axes, None), P(None)),
-            out_specs=P(None, None), check_rep=False,
+        return shard_map_over(
+            self.mesh, lambda x_local, idx_rep: _psum_row_gather(x_local, idx_rep, axes),
+            ((axes, None), (None,)), (None, None),
         )(self.x, idx)
 
     def prepare_for_solve(self) -> "ShardedGram":
@@ -798,37 +828,21 @@ class ShardedGram(_InstrumentedOp):
                         x_peer, v_peer = nxt
                 return acc
 
-            out = shard_map(
-                ring_body, mesh=self.mesh,
-                in_specs=(P(axes, None), P(axes, None)),
-                out_specs=P(axes, None), check_rep=False,
+            out = shard_map_over(
+                self.mesh, ring_body, ((axes, None), (axes, None)), (axes, None)
             )(self.x, v2)
-            self._count(_bump_mv, out)
-            return out[:, 0] if squeeze else out
-
-        def block_row(x_local, x_all, v_all):
-            i = jax.lax.axis_index(axes)
-            n_local = x_local.shape[0]
-            out = self._local_mv(x_local, x_all, v_all)
-            v_local = jax.lax.dynamic_slice_in_dim(v_all, i * n_local, n_local, axis=0)
-            out = out + self.params.noise * v_local
-            return jax.lax.all_gather(out, axes, tiled=True)
-
-        if self.x_full is not None:
-            out = shard_map(
-                block_row, mesh=self.mesh,
-                in_specs=(P(axes, None), P(None, None), P(None, None)),
-                out_specs=P(None, None), check_rep=False,
-            )(self.x, self.x_full, v2)
         else:
-            def body(x_local, v_all):
-                x_all = jax.lax.all_gather(x_local, axes, tiled=True)
-                return block_row(x_local, x_all, v_all)
+            def block_row(x_local, x_all, v_all):
+                i = jax.lax.axis_index(axes)
+                n_local = x_local.shape[0]
+                out = self._local_mv(x_local, x_all, v_all)
+                v_local = jax.lax.dynamic_slice_in_dim(
+                    v_all, i * n_local, n_local, axis=0
+                )
+                out = out + self.params.noise * v_local
+                return jax.lax.all_gather(out, axes, tiled=True)
 
-            out = shard_map(
-                body, mesh=self.mesh, in_specs=(P(axes, None), P(None, None)),
-                out_specs=P(None, None), check_rep=False,
-            )(self.x, v2)
+            out = self._panel_map(block_row, ((None, None),), (None, None), v2)
         self._count(_bump_mv, out)
         return out[:, 0] if squeeze else out
 
@@ -842,41 +856,28 @@ class ShardedGram(_InstrumentedOp):
         squeeze = u.ndim == 1
         u2 = u[:, None] if squeeze else u
 
-        def contract(x_local, xi, u_all):
-            i = jax.lax.axis_index(axes)
-            n_local = x_local.shape[0]
-            u_local = jax.lax.dynamic_slice_in_dim(u_all, i * n_local, n_local, axis=0)
-            part = self._local_mv(xi, x_local, u_local)
-            return jax.lax.psum(part, axes)
-
-        if self.x_full is not None:
-            xi = self.x_full[idx]  # gathered once per solve, indexed replicated
-            out = shard_map(
-                contract, mesh=self.mesh,
-                in_specs=(P(axes, None), P(None, None), P(None, None)),
-                out_specs=P(None, None), check_rep=False,
-            )(self.x, xi, u2)
-        elif self._resolve_comm() == "ring":
+        if self._resolve_comm() == "ring":
             def body_ring(x_local, idx_rep, u_local):
                 xi = _psum_row_gather(x_local, idx_rep, axes)
                 part = self._local_mv(xi, x_local, u_local)
                 return jax.lax.psum(part, axes)
 
-            out = shard_map(
-                body_ring, mesh=self.mesh,
-                in_specs=(P(axes, None), P(None), P(axes, None)),
-                out_specs=P(None, None), check_rep=False,
+            out = shard_map_over(
+                self.mesh, body_ring, ((axes, None), (None,), (axes, None)), (None, None)
             )(self.x, idx, u2)
         else:
-            def body(x_local, idx_rep, u_all):
-                x_all = jax.lax.all_gather(x_local, axes, tiled=True)
-                return contract(x_local, x_all[idx_rep], u_all)
+            def contract(x_local, x_all, idx_rep, u_all):
+                i = jax.lax.axis_index(axes)
+                n_local = x_local.shape[0]
+                u_local = jax.lax.dynamic_slice_in_dim(
+                    u_all, i * n_local, n_local, axis=0
+                )
+                part = self._local_mv(x_all[idx_rep], x_local, u_local)
+                return jax.lax.psum(part, axes)
 
-            out = shard_map(
-                body, mesh=self.mesh,
-                in_specs=(P(axes, None), P(None), P(None, None)),
-                out_specs=P(None, None), check_rep=False,
-            )(self.x, idx, u2)
+            out = self._panel_map(
+                contract, ((None,), (None, None)), (None, None), idx, u2
+            )
         self._count(_bump_rows, out)
         return out[:, 0] if squeeze else out
 
@@ -891,37 +892,22 @@ class ShardedGram(_InstrumentedOp):
         squeeze = u.ndim == 1
         u2 = u[:, None] if squeeze else u
 
-        def row_block(x_local, xi, u_rep):
-            out_local = self._local_mv(x_local, xi, u_rep)
-            return jax.lax.all_gather(out_local, axes, tiled=True)
-
-        if self.x_full is not None:
-            xi = self.x_full[idx]
-            out = shard_map(
-                row_block, mesh=self.mesh,
-                in_specs=(P(axes, None), P(None, None), P(None, None)),
-                out_specs=P(None, None), check_rep=False,
-            )(self.x, xi, u2)
-        elif self._resolve_comm() == "ring":
+        if self._resolve_comm() == "ring":
             def body_ring(x_local, idx_rep, u_rep):
                 xi = _psum_row_gather(x_local, idx_rep, axes)
                 return self._local_mv(x_local, xi, u_rep)
 
-            out = shard_map(
-                body_ring, mesh=self.mesh,
-                in_specs=(P(axes, None), P(None), P(None, None)),
-                out_specs=P(axes, None), check_rep=False,
+            out = shard_map_over(
+                self.mesh, body_ring, ((axes, None), (None,), (None, None)), (axes, None)
             )(self.x, idx, u2)
         else:
-            def body(x_local, idx_rep, u_rep):
-                x_all = jax.lax.all_gather(x_local, axes, tiled=True)
-                return row_block(x_local, x_all[idx_rep], u_rep)
+            def row_block(x_local, x_all, idx_rep, u_rep):
+                out_local = self._local_mv(x_local, x_all[idx_rep], u_rep)
+                return jax.lax.all_gather(out_local, axes, tiled=True)
 
-            out = shard_map(
-                body, mesh=self.mesh,
-                in_specs=(P(axes, None), P(None), P(None, None)),
-                out_specs=P(None, None), check_rep=False,
-            )(self.x, idx, u2)
+            out = self._panel_map(
+                row_block, ((None,), (None, None)), (None, None), idx, u2
+            )
         self._count(_bump_rows, out)
         return out[:, 0] if squeeze else out
 
@@ -935,8 +921,8 @@ class ShardedGram(_InstrumentedOp):
 
     def block_at(self, idx: jax.Array) -> jax.Array:
         """K[idx, idx] — gathered from the global (sharded) inputs; the |idx|×d
-        panel and |idx|² block are small and land replicated. Under ring the
-        panel comes from the masked psum (no all_gather of x)."""
+        panel and |idx|² block are small and land replicated. The panel comes
+        from the masked psum (no all_gather of x)."""
         xi = self._gather_rows(idx)
         return gram(self.params, xi, xi)
 

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from .kernels_fn import KernelParams, spectral_sample
-from .operators import FeatureOperator
+from .operators import FeatureOperator, shard_map_over
 
 
 @jax.tree_util.register_dataclass
@@ -158,16 +158,6 @@ class ShardedFourierFeatures(FeatureOperator):
     def num_features(self) -> int:
         return self.inner.num_features
 
-    def _shard_map(self, body, in_specs, out_specs):
-        from jax.experimental.shard_map import shard_map  # local: keeps rff importable early
-        from jax.sharding import PartitionSpec as P
-
-        return shard_map(
-            body, mesh=self.mesh,
-            in_specs=tuple(P(*s) for s in in_specs),
-            out_specs=P(*out_specs), check_rep=False,
-        )
-
     def phi_mv(self, x: jax.Array, w: jax.Array, *, backend: Optional[str] = None,
                precision: Optional[str] = None) -> jax.Array:
         """Φ(x) @ w with x row-sharded, w replicated → row-sharded (n, s-like).
@@ -180,8 +170,8 @@ class ShardedFourierFeatures(FeatureOperator):
             return self.inner.phi_mv(x_local, w_rep, backend=backend,
                                      precision=precision)
 
-        out = self._shard_map(
-            body, in_specs=((axes, None), (None, None)), out_specs=(axes, None)
+        out = shard_map_over(
+            self.mesh, body, in_specs=((axes, None), (None, None)), out_specs=(axes, None)
         )(x, w2)
         return out[:, 0] if squeeze else out
 
@@ -198,8 +188,8 @@ class ShardedFourierFeatures(FeatureOperator):
                                     precision=precision)
             return jax.lax.psum(t, axes)
 
-        out = self._shard_map(
-            body, in_specs=((axes, None), (axes, None)), out_specs=(None, None)
+        out = shard_map_over(
+            self.mesh, body, in_specs=((axes, None), (axes, None)), out_specs=(None, None)
         )(x, u2)
         return out[:, 0] if squeeze else out
 
@@ -220,8 +210,8 @@ class ShardedFourierFeatures(FeatureOperator):
             return self.inner.phi_mv(x_local, t, backend=backend,
                                      precision=precision)
 
-        out = self._shard_map(
-            body, in_specs=((axes, None), (axes, None)), out_specs=(axes, None)
+        out = shard_map_over(
+            self.mesh, body, in_specs=((axes, None), (axes, None)), out_specs=(axes, None)
         )(x, u2)
         return out[:, 0] if squeeze else out
 

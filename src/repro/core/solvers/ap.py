@@ -72,8 +72,11 @@ def solve_ap(
         fl = fl | jnp.where(healthy & ~ok, FLAG_NONFINITE, 0).astype(jnp.int32)
         delta = jnp.where((healthy & ok)[None, :], delta, 0.0)
         alpha = alpha.at[idx].add(delta)
-        r = r - op.rows_t_mv(idx, delta)  # r −= K[:, idx] @ Δ, fused
-        r = r.at[idx].add(-sigma2 * delta)
+        # r −= (K[:, idx] + σ²E_idx) Δ: the σ² scatter goes first, on the
+        # carried residual, because a mesh-sharded operator may return the
+        # fused K[:, idx] @ Δ row-sharded, and a scatter into a sharded array
+        # needs an output sharding the solver cannot know
+        r = r.at[idx].add(-sigma2 * delta) - op.rows_t_mv(idx, delta)
         return (alpha, r, fl), None
 
     fl0 = jnp.where(

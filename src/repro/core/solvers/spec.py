@@ -44,6 +44,7 @@ import jax.numpy as jnp
 from jax.scipy.linalg import cho_factor, cho_solve
 
 from ...kernels.ops import BACKENDS, FEATURE_BACKENDS, PRECISIONS
+from ..kernels_fn import HIGHEST
 from ..operators import require_capabilities
 from ..precond import jacobi_preconditioner, woodbury_from_factor
 from .ap import solve_ap
@@ -772,11 +773,14 @@ def solve_bordered(
         x0_blocks=None if x0 is None else [x0],
     )
     z = z_result.solution  # (n, k) = A⁻¹ B
-    schur = c_new + op.noise * jnp.eye(k, dtype=b_cols.dtype) - b_cols.T @ z
+    def dot(a, b):  # full fp32 on TPU too (its default is a bf16 pass)
+        return jnp.matmul(a, b, precision=HIGHEST)
+
+    schur = c_new + op.noise * jnp.eye(k, dtype=b_cols.dtype) - dot(b_cols.T, z)
     # symmetrise the fp drift from the iterative Z before factorizing — S is
     # S.P.D. by the Schur-complement theorem whenever the extended Gram is
     schur = 0.5 * (schur + schur.T)
     cho = cho_factor(schur, lower=True)
-    w = cho_solve(cho, rhs_new - b_cols.T @ sol_old)  # (k, m)
-    u = sol_old - z @ w  # (n, m)
+    w = cho_solve(cho, rhs_new - dot(b_cols.T, sol_old))  # (k, m)
+    u = sol_old - dot(z, w)  # (n, m)
     return jnp.concatenate([u, w], axis=0), z_result

@@ -12,13 +12,15 @@ tests/test_autotune.py).
 
 Resolution order:
 
-1. **Committed table** (``results/AUTOTUNE_gram.json``, overridable via the
-   ``REPRO_AUTOTUNE_TABLE`` env var): keys are
+1. **Committed table** (``<checkout>/results/AUTOTUNE_gram.json``, resolved
+   from this file's location so the working directory does not matter;
+   overridable via the ``REPRO_AUTOTUNE_TABLE`` env var): keys are
    ``"<family>|n<bucket>|d<bucket>|<dtype>"`` over the shape grid swept by
    ``benchmarks/bench_gram_kernel.py`` (which emits the artifact — see
    docs/kernels.md for how to regenerate it). Shapes bucket to the
    nearest-lower grid point, so any (n, d) resolves to a swept neighbourhood.
-2. **VMEM-budget heuristic** for unseen keys or a missing table: the largest
+2. **VMEM-budget heuristic** for unseen keys or a missing table (a table
+   that exists but cannot be read raises instead): the largest
    candidate block whose estimated per-tile footprint fits ``VMEM_BUDGET_BYTES``
    and that does not out-pad the problem (never a 512 tile for 300 rows).
 
@@ -31,6 +33,7 @@ from __future__ import annotations
 import functools
 import json
 import os
+from pathlib import Path
 
 #: Kernel families with distinct tile-footprint shapes.
 FAMILIES = ("gram", "rff")
@@ -58,8 +61,11 @@ RHS_WIDTH_ESTIMATE = 16
 #: Environment variable overriding the committed table path.
 AUTOTUNE_ENV = "REPRO_AUTOTUNE_TABLE"
 
-#: Default committed-table location (repo-root relative; the bench emits it).
-DEFAULT_TABLE_PATH = "results/AUTOTUNE_gram.json"
+#: Committed-table location in the checkout (this file is
+#: ``<checkout>/src/repro/kernels/``; the bench emits the table there).
+DEFAULT_TABLE_PATH = str(
+    Path(__file__).resolve().parents[3] / "results" / "AUTOTUNE_gram.json"
+)
 
 
 def _bucket(grid: tuple, v: int) -> int:
@@ -120,14 +126,21 @@ def _load_table(path: str) -> tuple:
     try:
         with open(path) as f:
             data = json.load(f)
-    except (OSError, ValueError):
+    except FileNotFoundError:
         return ()
+    except (OSError, ValueError) as e:
+        raise ValueError(
+            f"autotune table {path} exists but cannot be read ({e}); fix or "
+            f"regenerate it (benchmarks/bench_gram_kernel.py) rather than let "
+            f"block='auto' fall back to the heuristic in silence"
+        ) from e
     table = data.get("table", data) if isinstance(data, dict) else {}
     return tuple(sorted((str(k), int(v)) for k, v in table.items()))
 
 
 def load_table(path: str | None = None) -> dict:
-    """The committed autotune table as {key: block}; {} if absent/unreadable.
+    """The committed autotune table as {key: block}; {} if absent. A table
+    that exists but cannot be read raises ``ValueError``.
 
     Cached per path — call ``load_table.cache_clear()`` (forwarded to the inner
     cache) after regenerating the artifact in-process.

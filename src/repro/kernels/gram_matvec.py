@@ -61,6 +61,19 @@ def _cast_mxu(a, precision: str):
     return a
 
 
+def _mxu_dot(a, b, contract, precision: str):
+    """``dot_general`` of two tiles over the ``contract`` dims, operands cast
+    per the tile precision, accumulated in fp32. fp32 tiles contract at full
+    fp32 (``HIGHEST``): Mosaic's default takes one bf16 pass for float32
+    operands, and a solver's representer weights cancel so heavily in
+    K @ v that the rounding of K's entries dominates the result."""
+    return jax.lax.dot_general(
+        _cast_mxu(a, precision), _cast_mxu(b, precision), (contract, ((), ())),
+        precision=jax.lax.Precision.HIGHEST if precision == "fp32" else None,
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _pair_dists(x, z, precision: str):
     """Squared-distance tile via the matmul identity, honouring the precision.
 
@@ -75,9 +88,7 @@ def _pair_dists(x, z, precision: str):
     zf = zc.astype(jnp.float32)
     xn = jnp.sum(xf * xf, axis=-1)[:, None]
     zn = jnp.sum(zf * zf, axis=-1)[None, :]
-    inner = jax.lax.dot_general(
-        xc, zc, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    inner = _mxu_dot(xc, zc, ((1,), (1,)), precision)
     return xn + zn - 2.0 * inner
 
 
@@ -130,10 +141,7 @@ def _gram_matvec_kernel(
     v = v_ref[...]  # (bn, s)
     d2 = jnp.maximum(_pair_dists(x_ref[...], z_ref[...], precision), 0.0)
     k = signal * _cov_map(d2, kind)
-    acc_ref[...] += jax.lax.dot_general(
-        _cast_mxu(k, precision), _cast_mxu(v, precision),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _mxu_dot(k, v, ((1,), (0,)), precision)
     if jitter:
         # square blocking (bm == bn): diagonal tiles contribute jitter·I @ v = jitter·v
         @pl.when(i == j)
@@ -227,17 +235,11 @@ def _gram_matvec_bwd_kernel(
     else:
         # replicate autodiff's max(·, 0) clamp convention: 1 above, ½ at, 0 below
         mask = jnp.where(raw > 0.0, 1.0, jnp.where(raw == 0.0, 0.5, 0.0))
-    gv = jax.lax.dot_general(
-        _cast_mxu(rowv_ref[...], precision), _cast_mxu(colv_ref[...], precision),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (bm, bn) = ḡ_i · v_j
+    # (bm, bn) = ḡ_i · v_j
+    gv = _mxu_dot(rowv_ref[...], colv_ref[...], ((1,), (1,)), precision)
     w = kp * mask * gv
     acc_ws_ref[...] += jnp.sum(w, axis=1, keepdims=True)
-    acc_wz_ref[...] += jax.lax.dot_general(
-        _cast_mxu(w, precision), _cast_mxu(z, precision),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    )
+    acc_wz_ref[...] += _mxu_dot(w, z, ((1,), (0,)), precision)
 
     @pl.when(j == ncols - 1)
     def _flush():
@@ -357,7 +359,7 @@ def _gram_rows_pair_kernel(
     """
     ph, j = pl.program_id(0), pl.program_id(1)
     d2 = jnp.maximum(_pair_dists(xi_ref[...], x_ref[...], precision), 0.0)
-    k = _cast_mxu(_cov_map(d2, kind), precision)  # (bp, bn) panel tile
+    k = _cov_map(d2, kind)  # (bp, bn) panel tile
 
     @pl.when(ph == 0)
     def _accumulate():
@@ -365,10 +367,7 @@ def _gram_rows_pair_kernel(
         def _init():
             acc_ref[...] = -b_ref[...].astype(jnp.float32)
 
-        acc_ref[...] += jax.lax.dot_general(
-            k, _cast_mxu(look_ref[...], precision),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
+        acc_ref[...] += _mxu_dot(k, look_ref[...], ((1,), (0,)), precision)
 
         @pl.when(j == ncols - 1)
         def _finalize():
@@ -378,9 +377,8 @@ def _gram_rows_pair_kernel(
 
     @pl.when(ph == 1)
     def _contract():
-        o_ref[...] = jax.lax.dot_general(
-            k, _cast_mxu(acc_ref[...], precision),
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        o_ref[...] = _mxu_dot(
+            k, acc_ref[...], ((0,), (0,)), precision
         ).astype(o_ref.dtype)
 
 

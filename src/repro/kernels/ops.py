@@ -137,14 +137,15 @@ def _resolve_block(block, family: str, n: int, d: int, precision: str) -> int:
 def _dot(a: jax.Array, b: jax.Array, precision: str) -> jax.Array:
     """a @ b honouring the tile precision: bf16 operands, fp32 accumulation.
 
-    The fp32 path stays a plain ``@`` so existing results are bit-identical.
+    The fp32 path is a full-fp32 dot (``HIGHEST``): the TPU default would
+    run float32 operands through a reduced-precision bf16 pass.
     """
     if precision == "bf16":
         return jax.lax.dot_general(
             a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
         )
-    return a @ b
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
 def _pallas_gram_mv(params, x, v2, z, block, interpret, precision="fp32"):
@@ -200,7 +201,7 @@ def gram_mv(
     elif bk == "chunked":
         out = matvec(params, x, v2, z=z, row_chunk=row_chunk)
     else:
-        out = gram(params, x, z) @ v2
+        out = _dot(gram(params, x, z), v2, "fp32")
     if jitter is not None:
         out = out + jitter * v2
     return out[:, 0] if squeeze else out

@@ -34,16 +34,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .gram_matvec import _cast_mxu
+from .gram_matvec import _mxu_dot
 
 
 def _proj(x, om, precision):
     """The (bm, bf) projection tile x Ωᵀ — MXU operands cast per the tile
     precision, fp32 accumulation (see gram_matvec.TILE_PRECISIONS)."""
-    return jax.lax.dot_general(
-        _cast_mxu(x, precision), _cast_mxu(om, precision),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-    )
+    return _mxu_dot(x, om, ((1,), (1,)), precision)
 
 
 def _rff_kernel(
@@ -56,15 +53,10 @@ def _rff_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     proj = _proj(x_ref[...], om_ref[...], precision)  # (bm, bf)
-    wsin = _cast_mxu(wsin_ref[...], precision)
-    wcos = _cast_mxu(wcos_ref[...], precision)
+    wsin, wcos = wsin_ref[...], wcos_ref[...]
     acc_ref[...] += scale * (
-        jax.lax.dot_general(_cast_mxu(jnp.sin(proj), precision), wsin,
-                            (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        + jax.lax.dot_general(_cast_mxu(jnp.cos(proj), precision), wcos,
-                              (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
+        _mxu_dot(jnp.sin(proj), wsin, ((1,), (0,)), precision)
+        + _mxu_dot(jnp.cos(proj), wcos, ((1,), (0,)), precision)
     )
 
     @pl.when(j == nfeat - 1)
@@ -131,16 +123,11 @@ def _rff_t_kernel(
         accc_ref[...] = jnp.zeros_like(accc_ref)
 
     proj = _proj(x_ref[...], om_ref[...], precision)  # (bm, bf)
-    u = _cast_mxu(u_ref[...], precision)  # (bm, s)
-    # sin(proj)ᵀ @ u and cos(proj)ᵀ @ u — contract the row dimension on the MXU
-    accs_ref[...] += scale * jax.lax.dot_general(
-        _cast_mxu(jnp.sin(proj), precision), u, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (bf, s)
-    accc_ref[...] += scale * jax.lax.dot_general(
-        _cast_mxu(jnp.cos(proj), precision), u, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    u = u_ref[...]  # (bm, s)
+    # sin(proj)ᵀ @ u and cos(proj)ᵀ @ u (each (bf, s)) — contract the row
+    # dimension on the MXU
+    accs_ref[...] += scale * _mxu_dot(jnp.sin(proj), u, ((0,), (0,)), precision)
+    accc_ref[...] += scale * _mxu_dot(jnp.cos(proj), u, ((0,), (0,)), precision)
 
     @pl.when(i == nrows - 1)
     def _flush():
@@ -225,21 +212,11 @@ def _rff_bwd_kernel(
 
     c = c_ref[...]  # (bn, d)
     proj = _proj(r_ref[...], c, precision)  # (bm, bn)
-    a = jax.lax.dot_general(
-        _cast_mxu(p1_ref[...], precision), _cast_mxu(q1_ref[...], precision),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (bm, bn) = P₁_i · Q₁_j
-    b = jax.lax.dot_general(
-        _cast_mxu(p2_ref[...], precision), _cast_mxu(q2_ref[...], precision),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    # (bm, bn) = P₁_i · Q₁_j
+    a = _mxu_dot(p1_ref[...], q1_ref[...], ((1,), (1,)), precision)
+    b = _mxu_dot(p2_ref[...], q2_ref[...], ((1,), (1,)), precision)
     w = jnp.cos(proj) * a - jnp.sin(proj) * b
-    acc_ref[...] += scale * jax.lax.dot_general(
-        _cast_mxu(w, precision), _cast_mxu(c, precision),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    )  # (bm, d)
+    acc_ref[...] += scale * _mxu_dot(w, c, ((1,), (0,)), precision)  # (bm, d)
 
     @pl.when(j == ncols - 1)
     def _flush():
@@ -408,15 +385,9 @@ def _rff_pair_kernel(
             ts_ref[...] = jnp.zeros_like(ts_ref)
             tc_ref[...] = jnp.zeros_like(tc_ref)
 
-        u = _cast_mxu(u_ref[...], precision)  # (bm, s)
-        ts_ref[...] += scale * jax.lax.dot_general(
-            _cast_mxu(sn, precision), u, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (m_pad, s)
-        tc_ref[...] += scale * jax.lax.dot_general(
-            _cast_mxu(cs, precision), u, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        u = u_ref[...]  # (bm, s)
+        ts_ref[...] += scale * _mxu_dot(sn, u, ((0,), (0,)), precision)  # (m_pad, s)
+        tc_ref[...] += scale * _mxu_dot(cs, u, ((0,), (0,)), precision)
 
         @pl.when(i == nrows - 1)
         def _finalize():
@@ -429,14 +400,8 @@ def _rff_pair_kernel(
     def _apply():
         o_ref[...] = (
             scale * (
-                jax.lax.dot_general(
-                    _cast_mxu(sn, precision), _cast_mxu(ts_ref[...], precision),
-                    (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-                )
-                + jax.lax.dot_general(
-                    _cast_mxu(cs, precision), _cast_mxu(tc_ref[...], precision),
-                    (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-                )
+                _mxu_dot(sn, ts_ref[...], ((1,), (0,)), precision)
+                + _mxu_dot(cs, tc_ref[...], ((1,), (0,)), precision)
             )
         ).astype(o_ref.dtype)
 

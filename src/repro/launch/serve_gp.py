@@ -25,6 +25,12 @@ injects a transient matvec fault into that fraction of solve batches — the
 poisoned request is rescued solo through the escalation ladder and the
 failure counters (``escalations``/``failed``/``quarantined``/…) show up in
 the summary and the ``--json`` snapshot.
+
+Exit status: 1 when any request completed with ``exec_error`` (its batch
+raised on every retry — a compile error, an out-of-memory, a runtime fault),
+0 otherwise. The ``deadline_exceeded``, ``solver_failure`` and
+``quarantined`` outcomes are structured results the chaos knobs produce on
+purpose, so they leave the exit status at 0.
 """
 from __future__ import annotations
 
@@ -32,6 +38,7 @@ import argparse
 import itertools
 import json
 import random
+import sys
 import time
 
 import jax
@@ -39,6 +46,7 @@ import jax.numpy as jnp
 
 from ..core.kernels_fn import make_params
 from ..serve import GPEngine, PREDICT, SAMPLE, THOMPSON
+from .compile_cache import enable_compile_cache
 
 
 def synthetic_dataset(n: int, d: int, seed: int = 0):
@@ -147,6 +155,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json", action="store_true", help="print stats as JSON")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     mix = {}
     for part in args.mix.split(","):
@@ -247,6 +256,15 @@ def main(argv=None):
             print(f"[serve_gp] faults: " + " ".join(
                 f"{k}={v}" for k, v in sorted(faults.items())
             ))
+    exec_errors = [
+        c for c in (h.result() for h in handles if h.done)
+        if c.error is not None and c.error.get("code") == "exec_error"
+    ]
+    if exec_errors:
+        print(f"[serve_gp] {len(exec_errors)} requests failed in batch "
+              f"execution; first: {exec_errors[0].error['message']}",
+              file=sys.stderr)
+        return 1
     return 0
 
 

@@ -98,6 +98,31 @@ def test_missing_table_is_not_an_error(tmp_path, monkeypatch):
         autotune.load_table.cache_clear()
 
 
+@pytest.mark.parametrize("content", ["{not json", "[1, 2"])
+def test_unreadable_table_raises(tmp_path, monkeypatch, content):
+    path = tmp_path / "broken.json"
+    path.write_text(content)
+    monkeypatch.setenv(autotune.AUTOTUNE_ENV, str(path))
+    autotune.load_table.cache_clear()
+    try:
+        with pytest.raises(ValueError, match="cannot be read"):
+            autotune.load_table()
+    finally:
+        autotune.load_table.cache_clear()
+
+
+def test_default_table_resolves_from_checkout(tmp_path, monkeypatch):
+    """The committed table is found from any working directory."""
+    monkeypatch.delenv(autotune.AUTOTUNE_ENV, raising=False)
+    monkeypatch.chdir(tmp_path)
+    autotune.load_table.cache_clear()
+    try:
+        table = autotune.load_table()
+        assert set(table) == autotune.expected_keys()
+    finally:
+        autotune.load_table.cache_clear()
+
+
 # ---------------------------------------------------------------------------
 # block="auto" resolves at trace time: correct values, no retraces
 # ---------------------------------------------------------------------------

@@ -543,13 +543,14 @@ def test_engine_update_policies_and_cache_purge(small_problem):
 
 
 def test_engine_compaction_trigger(small_problem):
-    """A drift budget below the lowrank path's per-update certified residual
-    forces the auto fallback: the engine re-solves in full (compaction), and
-    the resulting state is certified at the spec tolerance."""
+    """A zero drift budget forces the auto fallback whatever the lowrank
+    path's certified residual (any float32 residual is > 0): the engine
+    re-solves in full (compaction), and the resulting state is certified at
+    the spec tolerance."""
     x_new = small_problem["x"][:6] + 0.02
     y_new = small_problem["y"][:6]
-    eng = _engine(small_problem, compaction_tol_factor=1.0)
-    eng.add_observations(x_new, y_new)  # certified drift ~1.5× tol > 1× tol
+    eng = _engine(small_problem, compaction_tol_factor=0.0)
+    eng.add_observations(x_new, y_new)
     snap = eng.stats()
     assert snap["compactions"] == 1
     assert snap["lowrank_updates"] == 0
@@ -628,3 +629,30 @@ def test_refit_savings_rebaseline(small_problem):
     warm_iters = snap2["refit_iterations"] - cold_iters
     assert snap2["refit_baseline_n"] == small_problem["n"] + 4  # unchanged
     assert snap2["refit_iterations_saved"] == max(0, cold_iters - warm_iters)
+
+
+@pytest.mark.parametrize("raise_in", [None, "_execute_predict", "_execute_solve"])
+def test_serve_gp_exit_status_on_exec_error(monkeypatch, capsys, raise_in):
+    """``serve_gp`` exits 1 when a batch raised on every retry (its requests
+    completed with ``exec_error``) and 0 on a clean run. The injected fault
+    is an exception, not a numeric condition."""
+    from repro.launch import serve_gp
+
+    monkeypatch.setattr(serve_gp, "enable_compile_cache", lambda: None)
+    if raise_in is not None:
+        def boom(self, plan):
+            raise RuntimeError("injected batch execution fault")
+
+        monkeypatch.setattr(GPEngine, raise_in, boom)
+    rc = serve_gp.main([
+        "--n", "64", "--d", "2", "--requests", "4", "--depth", "2",
+        "--num-features", "64", "--num-samples", "2",
+        "--mix", "predict=1,sample=1", "--repeat", "0",
+    ])
+    err = capsys.readouterr().err
+    if raise_in is None:
+        assert rc == 0
+        assert "exec" not in err
+    else:
+        assert rc == 1
+        assert "injected batch execution fault" in err
