@@ -37,7 +37,6 @@ One process drives the chip; nothing else is started.
 from __future__ import annotations
 
 import argparse
-import contextlib
 import json
 import sys
 import time
@@ -78,56 +77,6 @@ def check(cond, message: str) -> None:
 
 def say(message: str) -> None:
     print(f"[chip_smoke] {message}", flush=True)
-
-
-class CompileMonitor:
-    """Backend compile time and count, and persistent-cache hits and misses,
-    from JAX's monitoring events (process-wide listeners)."""
-
-    COMPILE = "/jax/core/compile/backend_compile_duration"
-    HIT = "/jax/compilation_cache/cache_hits"
-    MISS = "/jax/compilation_cache/cache_misses"
-
-    def __init__(self):
-        import jax
-
-        self.compile_s = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        self.cache_misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, secs, **_):
-        if event == self.COMPILE:
-            self.compile_s += secs
-            self.compiles += 1
-
-    def _on_event(self, event, **_):
-        if event == self.HIT:
-            self.cache_hits += 1
-        elif event == self.MISS:
-            self.cache_misses += 1
-
-    def snapshot(self) -> tuple:
-        return self.compile_s, self.compiles, self.cache_hits, self.cache_misses
-
-
-class Phases:
-    """Wall time of each phase with its compile time reported apart."""
-
-    def __init__(self, monitor: CompileMonitor):
-        self.monitor = monitor
-
-    @contextlib.contextmanager
-    def phase(self, name: str):
-        c0, n0, h0, m0 = self.monitor.snapshot()
-        t0 = time.perf_counter()
-        yield
-        wall = time.perf_counter() - t0
-        c1, n1, h1, m1 = self.monitor.snapshot()
-        say(f"phase {name}: wall_s={wall:.3f} compile_s={c1 - c0:.3f} "
-            f"compiles={n1 - n0} cache_hits={h1 - h0} cache_misses={m1 - m0}")
 
 
 # ---------------------------------------------------------------------------
@@ -187,7 +136,7 @@ def backend_counters() -> dict:
                 features=dict(FEATURE_TRACE_COUNTS))
 
 
-def serving_phase(x, y, x_new, y_new, params, phases: Phases, *, seed: int,
+def serving_phase(x, y, x_new, y_new, params, *, seed: int,
                   num_samples: int = 16, requests: int = 30,
                   parity_rows: int = PARITY_ROWS) -> dict:
     """Fit a ``GPEngine``, serve mixed traffic, append rows, check the results
@@ -201,12 +150,13 @@ def serving_phase(x, y, x_new, y_new, params, phases: Phases, *, seed: int,
     )
     from repro.launch.serve_gp import drive, request_stream
     from repro.serve import GPEngine, PREDICT, SAMPLE, SOLVE_KINDS, THOMPSON
+    from repro.serve.metrics import span
 
     reset_matvec_trace_counts()
     reset_feature_trace_counts()
     d = x.shape[1]
 
-    with phases.phase("fit"):
+    with span("fit"):
         engine = GPEngine(params, x, y, spec="cg", num_samples=num_samples,
                           seed=seed)
         fit = engine.state
@@ -222,7 +172,7 @@ def serving_phase(x, y, x_new, y_new, params, phases: Phases, *, seed: int,
                                  num_rows=16, num_samples=4))
     repeats = [r for r in stream if r[0] in SOLVE_KINDS][:6]  # warm starts
     stream += repeats
-    with phases.phase("serve"):
+    with span("serve"):
         handles, _ = drive(engine, stream, depth=8)
         jax.block_until_ready([h.result().value for h in handles if h.done])
     comps = [h.result() for h in handles]
@@ -242,7 +192,7 @@ def serving_phase(x, y, x_new, y_new, params, phases: Phases, *, seed: int,
         check(snap[key] == 0, f"engine stats {key}={snap[key]}")
     check(snap["warm_hits"] > 0, "no warm-start hit: repeat seeds missed")
 
-    with phases.phase("write"):
+    with span("write"):
         engine.add_observations(x_new, y_new)
         jax.block_until_ready(engine.state.post.alpha)
     snap = engine.stats()
@@ -259,7 +209,7 @@ def serving_phase(x, y, x_new, y_new, params, phases: Phases, *, seed: int,
     check(counters["features"]["features"] == 0,
           f"feature matrix materialised: {counters['features']}")
 
-    with phases.phase("reference"):
+    with span("reference"):
         idx = jax.random.choice(jax.random.PRNGKey(seed + 2), fit.n,
                                 (parity_rows,), replace=False)
         v = jnp.concatenate([fit.post.v_mean[:, None], fit.post.alpha], axis=1)
@@ -288,16 +238,17 @@ def serving_phase(x, y, x_new, y_new, params, phases: Phases, *, seed: int,
                 stats=snap, counters=counters)
 
 
-def run_one_chip(args, phases: Phases) -> None:
+def run_one_chip(args) -> None:
     from repro.core.kernels_fn import make_params
     from repro.data.pipeline import regression_dataset
+    from repro.serve.metrics import span
 
-    with phases.phase("data"):
+    with span("data"):
         data = regression_dataset("pol", seed=args.seed)
     x, y = data["x"], data["y"]
     params = make_params("matern32", lengthscale=POL_LENGTHSCALE, signal=1.0,
                          noise=POL_NOISE, d=x.shape[1])
-    serving_phase(x, y, data["x_test"][:4], data["y_test"][:4], params, phases,
+    serving_phase(x, y, data["x_test"][:4], data["y_test"][:4], params,
                   seed=args.seed)
 
 
@@ -314,7 +265,7 @@ def _collectives(hlo: str) -> dict:
                        "reduce-scatter", "all-to-all")}
 
 
-def sharded_phase(x, y, params, mesh, phases: Phases, *, iters: int = ROAD_ITERS,
+def sharded_phase(x, y, params, mesh, *, iters: int = ROAD_ITERS,
                   bound: float = SHARDED_BOUND) -> dict:
     """CG at a fixed budget through ``distributed_solve`` (gather and ring)
     on row-sharded ``x``, compared with the one-chip solve of the same
@@ -328,11 +279,12 @@ def sharded_phase(x, y, params, mesh, phases: Phases, *, iters: int = ROAD_ITERS
     from repro.kernels.ops import (
         MATVEC_TRACE_COUNTS, reset_matvec_trace_counts,
     )
+    from repro.serve.metrics import span
 
     reset_matvec_trace_counts()
     spec = CG(max_iters=iters, tol=1e-30)  # the budget binds
     devices = list(mesh.devices.flat)
-    with phases.phase("shard"):
+    with span("shard"):
         xs = shard_training_rows(mesh, x)
         jax.block_until_ready(xs)
     held = {}
@@ -348,7 +300,7 @@ def sharded_phase(x, y, params, mesh, phases: Phases, *, iters: int = ROAD_ITERS
     probe = jax.random.normal(jax.random.PRNGKey(0), (x.shape[0], 1), x.dtype)
     sols, mvs = {}, {}
     for comm in ("gather", "ring"):
-        with phases.phase(f"solve_{comm}"):
+        with span(f"solve_{comm}"):
             res = distributed_solve(params, xs, y, mesh, spec, comm=comm)
             sols[comm] = jax.device_get(res.solution).reshape(-1, 1)
         check(int(res.iterations) == iters,
@@ -364,7 +316,7 @@ def sharded_phase(x, y, params, mesh, phases: Phases, *, iters: int = ROAD_ITERS
              for d in devices}
     say(f"peak_bytes_in_use per device: {peaks}")
 
-    with phases.phase("solve_one_chip"):
+    with span("solve_one_chip"):
         op1 = Gram(x=jax.device_put(x, devices[0]), params=params)
         ref = solve(op1, jax.device_put(y, devices[0]), spec)
         ref_sol = jax.device_get(ref.solution).reshape(-1, 1)
@@ -389,15 +341,16 @@ def sharded_phase(x, y, params, mesh, phases: Phases, *, iters: int = ROAD_ITERS
     return dict(diffs=diffs, mv_errs=mv_errs, held=held, peaks=peaks)
 
 
-def run_four_chips(args, phases: Phases) -> None:
+def run_four_chips(args) -> None:
     import jax
 
     from repro.core.kernels_fn import make_params
     from repro.data.pipeline import regression_dataset
+    from repro.serve.metrics import span
 
     check(len(jax.devices()) == 4,
           f"--chips 4 needs 4 devices, found {len(jax.devices())}")
-    with phases.phase("data"):
+    with span("data"):
         data = regression_dataset("3droad", seed=args.seed, n_test=1)
     n = data["n"] - data["n"] % 4  # equal shards: 434,874 -> 434,872 rows
     x, y = data["x"][:n], data["y"][:n]
@@ -405,10 +358,23 @@ def run_four_chips(args, phases: Phases) -> None:
     params = make_params("matern32", lengthscale=ROAD_LENGTHSCALE, signal=1.0,
                          noise=ROAD_NOISE, d=x.shape[1])
     mesh = jax.make_mesh((4,), ("data",))
-    sharded_phase(x, y, params, mesh, phases)
+    sharded_phase(x, y, params, mesh)
 
 
 # ---------------------------------------------------------------------------
+
+
+def report_jit() -> dict:
+    """Print the process's tracing, lowering and compile work per phase
+    (``repro.serve.metrics``: the smoke's own spans and the engine's)."""
+    from repro.serve.metrics import jit_totals
+
+    totals = jit_totals()
+    for phase, c in sorted(totals.items()):
+        say(f"jit {phase}: trace_s={c['trace_s']:.3f} lower_s={c['lower_s']:.3f} "
+            f"compile_s={c['compile_s']:.3f} compiles={c['compiles']} "
+            f"cache_hits={c['cache_hits']} cache_misses={c['cache_misses']}")
+    return totals
 
 
 def main(argv=None) -> int:
@@ -436,20 +402,17 @@ def main(argv=None) -> int:
     say(f"device: platform={dev.platform} kind={dev.device_kind} "
         f"count={len(jax.devices())} jax={jax.__version__}")
     say(f"compile cache: {enable_compile_cache()}")
-    monitor = CompileMonitor()
-    phases = Phases(monitor)
     t0 = time.perf_counter()
     try:
         if args.chips == 4:
-            run_four_chips(args, phases)
+            run_four_chips(args)
         else:
-            run_one_chip(args, phases)
+            run_one_chip(args)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
-    say(f"total: wall_s={time.perf_counter() - t0:.3f} "
-        f"compile_s={monitor.compile_s:.3f} compiles={monitor.compiles} "
-        f"cache_hits={monitor.cache_hits} cache_misses={monitor.cache_misses}")
+    say(f"total: wall_s={time.perf_counter() - t0:.3f}")
+    report_jit()
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices()),

@@ -237,9 +237,15 @@ def main(argv=None):
         print(f"[serve_gp] solver iterations={snap['solver_iterations']} "
               f"matvecs={snap['solver_matvecs']}; warm hits={snap['warm_hits']} "
               f"(saved {snap['iterations_saved_warm']} iters)")
-        print(f"[serve_gp] latency p50={snap['total_latency_p50_s']*1e3:.1f}ms "
-              f"p99={snap['total_latency_p99_s']*1e3:.1f}ms "
-              f"queue p50={snap['queue_latency_p50_s']*1e3:.1f}ms")
+        # "step" and "batch" enclose the other phases of a step
+        costliest = sorted(
+            ((p, v) for p, v in snap["phases"].items()
+             if p not in ("step", "batch")),
+            key=lambda kv: -kv[1]["wall_s"],
+        )[:3]
+        print(f"[serve_gp] queue wait mean={snap['queue_wait_mean_s']*1e3:.1f}ms; "
+              f"costliest phases: " + " ".join(
+                  f"{p}={v['wall_s']:.3f}s/{v['calls']}" for p, v in costliest))
         if snap["refits"]:
             print(f"[serve_gp] writes: refits={snap['refits']} "
                   f"lowrank_updates={snap['lowrank_updates']} "

@@ -186,6 +186,8 @@ class GPEngine:
         self.quarantine_after = int(quarantine_after)
         self.escalation = escalation
         self._op_transform = operator_transform
+        # first, so the JIT listener sees the fit (under "(none)")
+        self._stats = EngineStats()
         key = jax.random.PRNGKey(seed) if key is None else key
         kf, self._solver_key = jax.random.split(key)
         self.state: PosteriorState = fit_state(
@@ -198,7 +200,6 @@ class GPEngine:
             max_skips=max_skips,
         )
         self.cache = WarmStartCache(max_entries=warm_cache_entries)
-        self._stats = EngineStats()
         self._ids = itertools.count()
         self._auto_seeds = itertools.count()
         self._handles: dict = {}
@@ -244,6 +245,10 @@ class GPEngine:
         quarantined (kind, seed) identity completes immediately with a
         ``quarantined`` error.
         """
+        with self._stats.span("submit", kind=kind):
+            return self._submit(kind, xs, num_samples, seed, deadline_s, options)
+
+    def _submit(self, kind, xs, num_samples, seed, deadline_s, options):
         if kind not in KINDS:
             raise ValueError(f"unknown request kind {kind!r}; expected one of {KINDS}")
         if (
@@ -354,37 +359,66 @@ class GPEngine:
         accounting: ``queue_s`` is arrival → batch start on the engine clock;
         ``exec_s`` is the batch's measured compute wall (shared by every
         request in the batch, as is the solve's iteration/matvec spend).
+
+        Spans (``gp.step`` and its phases) and one ``gp.counters`` event
+        after it: docs/serving.md, "Observability".
         """
-        now = self._clock()
+        with self._stats.span("step"):
+            completions = self._step()
+        self._stats.emit_counters()
+        return completions
+
+    def _step(self) -> List[Completion]:
         completions: List[Completion] = []
-        for req in self.scheduler.expire(now):
-            self._stats.deadline_misses += 1
-            completions.append(
-                self._fail(
-                    req,
-                    code="deadline_exceeded",
-                    message=(
-                        f"request {req.id} ({req.kind}) expired in queue: "
-                        f"deadline {req.deadline:.3f} < now {now:.3f}"
-                    ),
-                    deadline=req.deadline,
-                    now=now,
+        with self._stats.span("schedule"):
+            now = self._clock()
+            for req in self.scheduler.expire(now):
+                self._stats.deadline_misses += 1
+                completions.append(
+                    self._fail(
+                        req,
+                        code="deadline_exceeded",
+                        message=(
+                            f"request {req.id} ({req.kind}) expired in queue: "
+                            f"deadline {req.deadline:.3f} < now {now:.3f}"
+                        ),
+                        deadline=req.deadline,
+                        now=now,
+                    )
                 )
-            )
-        plan = self.scheduler.next_batch()
+            plan = self.scheduler.next_batch()
         if plan is None:
             return completions
         t_start = self._clock()
+        self._stats.batch_started(
+            len(plan.requests), sum(t_start - r.arrival for r in plan.requests)
+        )
+        if plan.group == GROUP_PREDICT:
+            shape = dict(rows=plan.max_rows,
+                         bucket=bucket(plan.max_rows, self.row_bucket_min))
+        else:
+            shape = dict(columns=plan.total_columns,
+                         bucket=bucket(plan.total_columns, self.col_bucket_min))
+        with self._stats.span("batch", group=plan.group,
+                              requests=len(plan.requests), **shape):
+            self._execute(plan, t_start, completions)
+        return completions
+
+    def _execute(self, plan: BatchPlan, t_start: float,
+                 completions: List[Completion]) -> None:
+        """Execute ``plan`` (with retries) and append its completions."""
         t0 = time.perf_counter()
         attempt = 0
         while True:
             try:
                 if plan.group == GROUP_PREDICT:
-                    values, extra = self._execute_predict(plan)
+                    with self._stats.span("predict"):
+                        values, extra = self._execute_predict(plan)
                     errors: dict = {}
                 else:
                     values, extra, errors = self._execute_solve(plan)
-                jax.block_until_ready([list(v.values()) for v in values])
+                with self._stats.span("block"):
+                    jax.block_until_ready([list(v.values()) for v in values])
                 break
             except Exception as exc:  # noqa: BLE001 — isolation boundary:
                 # a raising batch must fail structurally, not kill the loop
@@ -399,43 +433,42 @@ class GPEngine:
                                 f"{attempt} attempts: {exc!r}",
                             )
                         )
-                    return completions
+                    return
                 self._stats.retries += 1
                 time.sleep(self.retry_backoff_s * attempt)
         exec_s = time.perf_counter() - t0
 
         self._stats.steps += 1
         self._stats.bump_batch(plan.group)
-        for req, value in zip(plan.requests, values):
-            queue_s = t_start - req.arrival
-            error = errors.get(req.id)
-            if error is not None:
-                comp = self._fail(req, **error)
-                if error.get("code") == "solver_failure":
-                    self._strike(req)
+        with self._stats.span("complete"):
+            for req, value in zip(plan.requests, values):
+                queue_s = t_start - req.arrival
+                error = errors.get(req.id)
+                if error is not None:
+                    comp = self._fail(req, **error)
+                    if error.get("code") == "solver_failure":
+                        self._strike(req)
+                    completions.append(comp)
+                    continue
+                metrics = dict(
+                    queue_s=queue_s,
+                    exec_s=exec_s,
+                    total_s=queue_s + exec_s,
+                    batch_requests=len(plan.requests),
+                    group=plan.group,
+                    **extra,
+                )
+                if req.kind in SOLVE_KINDS:
+                    metrics["warm"] = req.warm
+                if req.options.get("degraded"):
+                    metrics["degraded"] = True
+                comp = Completion(
+                    request_id=req.id, kind=req.kind, value=value,
+                    metrics=metrics,
+                )
+                self._handles.pop(req.id)._complete(comp)
+                self._stats.bump_kind(req.kind)
                 completions.append(comp)
-                continue
-            metrics = dict(
-                queue_s=queue_s,
-                exec_s=exec_s,
-                total_s=queue_s + exec_s,
-                batch_requests=len(plan.requests),
-                group=plan.group,
-                **extra,
-            )
-            if req.kind in SOLVE_KINDS:
-                metrics["warm"] = req.warm
-            if req.options.get("degraded"):
-                metrics["degraded"] = True
-            comp = Completion(
-                request_id=req.id, kind=req.kind, value=value, metrics=metrics
-            )
-            self._handles.pop(req.id)._complete(comp)
-            self._stats.bump_kind(req.kind)
-            self._stats.queue_latencies.append(queue_s)
-            self._stats.total_latencies.append(queue_s + exec_s)
-            completions.append(comp)
-        return completions
 
     def _strike(self, req) -> None:
         """Record a failed rescue; quarantine the (kind, seed) identity past
@@ -511,45 +544,50 @@ class GPEngine:
         untouched — their payloads are bit-identical to a fault-free batch.
         """
         state = self.state
-        op = state.operator()
-        if self._op_transform is not None:
-            # wrappers can't survive solve()'s dataclasses.replace backend
-            # pinning, so pin the inner operator first, then wrap
-            op = self._op_transform(_pin_backend(op, self.spec))
+        span = self._stats.span
         n = state.n
-        per_req = [self._request_draws(r) for r in plan.requests]
-        widths = [r.num_samples for r in plan.requests]
-        offsets = np.concatenate([[0], np.cumsum(widths)])
-        total = int(offsets[-1])
-        cbucket = bucket(total, self.col_bucket_min)
+        with span("solve.rhs"):
+            op = state.operator()
+            if self._op_transform is not None:
+                # wrappers can't survive solve()'s dataclasses.replace backend
+                # pinning, so pin the inner operator first, then wrap
+                op = self._op_transform(_pin_backend(op, self.spec))
+            per_req = [self._request_draws(r) for r in plan.requests]
+            widths = [r.num_samples for r in plan.requests]
+            offsets = np.concatenate([[0], np.cumsum(widths)])
+            total = int(offsets[-1])
+            cbucket = bucket(total, self.col_bucket_min)
 
-        w_cat = jnp.concatenate([w for w, _, _ in per_req], axis=1)
-        delta = jnp.concatenate(
-            [eps / state.params.noise for _, eps, _ in per_req], axis=1
-        )
-        pad = cbucket - total
-        if pad:
-            w_cat = jnp.pad(w_cat, ((0, 0), (0, pad)))
-            delta = jnp.pad(delta, ((0, 0), (0, pad)))
-        # one fused feature matvec builds every request's RHS columns (padded
-        # zero-weight columns give zero columns, which converge instantly)
-        data = state.prior.phi_mv(state.x, w_cat)
+            w_cat = jnp.concatenate([w for w, _, _ in per_req], axis=1)
+            delta = jnp.concatenate(
+                [eps / state.params.noise for _, eps, _ in per_req], axis=1
+            )
+            pad = cbucket - total
+            if pad:
+                w_cat = jnp.pad(w_cat, ((0, 0), (0, pad)))
+                delta = jnp.pad(delta, ((0, 0), (0, pad)))
+            # one fused feature matvec builds every request's RHS columns
+            # (padded zero-weight columns give zero columns, which converge
+            # instantly)
+            data = state.prior.phi_mv(state.x, w_cat)
 
         x0 = None
         if plan.group == GROUP_SOLVE_WARM:
-            cols = np.zeros((n, cbucket), dtype=data.dtype)
-            for req, lo, hi in zip(plan.requests, offsets[:-1], offsets[1:]):
-                hit = self.cache.lookup(state.hypers_key, req.kind, req.seed)
-                if hit is not None and hit.shape == (n, req.num_samples):
-                    cols[:, lo:hi] = hit
-                    self._stats.warm_hits += 1
-                else:  # probe said warm but the entry aged out — cold column
-                    self._stats.warm_misses += 1
-            x0 = jnp.asarray(cols, dtype=data.dtype)
-        skey = jax.random.fold_in(self._solver_key, self._stats.solves)
-        res = solve(op, data, self.spec, key=skey, x0=x0, delta=delta)
-        iters = int(res.iterations)
-        matvecs = int(res.matvecs)
+            with span("solve.warm"):
+                cols = np.zeros((n, cbucket), dtype=data.dtype)
+                for req, lo, hi in zip(plan.requests, offsets[:-1], offsets[1:]):
+                    hit = self.cache.lookup(state.hypers_key, req.kind, req.seed)
+                    if hit is not None and hit.shape == (n, req.num_samples):
+                        cols[:, lo:hi] = hit
+                        self._stats.warm_hits += 1
+                    else:  # probe said warm but the entry aged out — cold column
+                        self._stats.warm_misses += 1
+                x0 = jnp.asarray(cols, dtype=data.dtype)
+        with span("solve.cg"):
+            skey = jax.random.fold_in(self._solver_key, self._stats.solves)
+            res = solve(op, data, self.spec, key=skey, x0=x0, delta=delta)
+            iters = int(res.iterations)
+            matvecs = int(res.matvecs)
         self._stats.solves += 1
         self._stats.rhs_columns += total
         self._stats.padded_columns += pad
@@ -563,142 +601,68 @@ class GPEngine:
         else:
             self._last_cold_iters = iters
 
-        # ---- fault isolation: map flagged columns back to their requests,
-        # rescue each affected request solo, fail the unrescuable ones
-        flags = np.atleast_1d(np.asarray(jax.device_get(res.flags)))
-        if flags.size == 1 and cbucket > 1:
-            flags = np.full((cbucket,), int(flags[0]))
-        bad = (flags[:total].astype(np.int64) & FROZEN_FLAGS) != 0
-        errors: dict = {}
-        rescued: dict = {}
-        if bad.any():
-            for req, (w_req, eps_req, _), lo, hi in zip(
-                plan.requests, per_req, offsets[:-1], offsets[1:]
-            ):
-                if not bad[lo:hi].any():
-                    continue
-                req_flags = [int(f) for f in flags[lo:hi]]
-                names = flag_names(int(np.bitwise_or.reduce(flags[lo:hi])))
-                if self.escalation is None:
-                    errors[req.id] = dict(
-                        code="solver_failure",
-                        message=(
-                            f"request {req.id} ({req.kind}) columns flagged "
-                            f"({', '.join(names)}) and rescue is disabled"
-                        ),
-                        flags=req_flags,
-                    )
-                    continue
-                self._stats.escalations += 1
-                data_req = state.prior.phi_mv(state.x, w_req)
-                rkey = jax.random.fold_in(
-                    self._solver_key, 20_000_000 + req.id
-                )
-                report = solve_robust(
-                    op,
-                    data_req,
-                    self.spec,
-                    key=rkey,
-                    delta=eps_req / state.params.noise,
-                    policy=self.escalation,
-                )
-                if report.failed_columns:
-                    errors[req.id] = dict(
-                        code="solver_failure",
-                        message=(
-                            f"request {req.id} ({req.kind}) columns flagged "
-                            f"({', '.join(names)}); escalation ladder "
-                            f"{report.ladder or ['(empty)']} could not recover "
-                            f"columns {report.failed_columns}"
-                        ),
-                        flags=req_flags,
-                        rungs=list(report.ladder),
-                    )
-                else:
-                    rescued[req.id] = report.result.solution
-
-        for req, lo, hi in zip(plan.requests, offsets[:-1], offsets[1:]):
-            if req.id in errors:
-                continue  # never cache a poisoned solution
-            sol = rescued.get(req.id)
-            if sol is None:
-                sol = res.solution[:, lo:hi]
-            self.cache.store(state.hypers_key, req.kind, req.seed, sol)
+        with span("solve.flags"):
+            errors, rescued = self._isolate_faults(
+                plan, op, res, per_req, offsets, total, cbucket
+            )
 
         values_by_id = {}
-        # one batched pathwise evaluation serves every sample request: their
-        # query blocks stack row-wise, the batch's weight/representer columns
-        # ride whole (padded zero columns are exact mean paths), and each
-        # request's payload is the (rows, columns) sub-block at its offsets
-        sample_at = [
-            (req, int(lo)) for req, lo in zip(plan.requests, offsets[:-1])
-            if req.kind == SAMPLE
-            and req.id not in errors and req.id not in rescued
-        ]
-        if sample_at:
-            row_offsets, r_total = [], 0
-            for req, _ in sample_at:
-                row_offsets.append(r_total)
-                r_total += req.num_rows
-            rbucket = bucket(r_total, self.row_bucket_min)
-            xs_all = jnp.concatenate([req.xs for req, _ in sample_at], axis=0)
-            xs_pad = jnp.pad(xs_all, ((0, rbucket - r_total), (0, 0)))
-            vals = state.post.sample_paths(xs_pad, w_cat, res.solution)
-            for (req, lo), ro in zip(sample_at, row_offsets):
-                values_by_id[req.id] = {
-                    "samples": vals[ro : ro + req.num_rows,
-                                    lo : lo + req.num_samples]
-                }
+        with span("solve.paths"):
+            for req, lo, hi in zip(plan.requests, offsets[:-1], offsets[1:]):
+                if req.id in errors:
+                    continue  # never cache a poisoned solution
+                sol = rescued.get(req.id)
+                if sol is None:
+                    sol = res.solution[:, lo:hi]
+                self.cache.store(state.hypers_key, req.kind, req.seed, sol)
 
-        # rescued sample requests get a solo pathwise pass over the rescued
-        # representer block (cheap: the solve already happened in the ladder)
-        for req, (w_req, _, _) in zip(plan.requests, per_req):
-            if req.kind == SAMPLE and req.id in rescued:
-                values_by_id[req.id] = {
-                    "samples": state.post.sample_paths(
-                        req.xs, w_req, rescued[req.id]
-                    )
-                }
+            # one batched pathwise evaluation serves every sample request:
+            # their query blocks stack row-wise, the batch's weight/representer
+            # columns ride whole (padded zero columns are exact mean paths),
+            # and each request's payload is the (rows, columns) sub-block at
+            # its offsets
+            sample_at = [
+                (req, int(lo)) for req, lo in zip(plan.requests, offsets[:-1])
+                if req.kind == SAMPLE
+                and req.id not in errors and req.id not in rescued
+            ]
+            if sample_at:
+                row_offsets, r_total = [], 0
+                for req, _ in sample_at:
+                    row_offsets.append(r_total)
+                    r_total += req.num_rows
+                rbucket = bucket(r_total, self.row_bucket_min)
+                xs_all = jnp.concatenate([req.xs for req, _ in sample_at], axis=0)
+                xs_pad = jnp.pad(xs_all, ((0, rbucket - r_total), (0, 0)))
+                vals = state.post.sample_paths(xs_pad, w_cat, res.solution)
+                for (req, lo), ro in zip(sample_at, row_offsets):
+                    values_by_id[req.id] = {
+                        "samples": vals[ro : ro + req.num_rows,
+                                        lo : lo + req.num_samples]
+                    }
+
+            # rescued sample requests get a solo pathwise pass over the rescued
+            # representer block (cheap: the solve already happened in the
+            # ladder)
+            for req, (w_req, _, _) in zip(plan.requests, per_req):
+                if req.kind == SAMPLE and req.id in rescued:
+                    values_by_id[req.id] = {
+                        "samples": state.post.sample_paths(
+                            req.xs, w_req, rescued[req.id]
+                        )
+                    }
 
         for req, (_, _, ka), lo, hi in zip(
             plan.requests, per_req, offsets[:-1], offsets[1:]
         ):
             if req.kind != THOMPSON or req.id in errors:
                 continue
-            # THOMPSON: ascend each fresh sample path (§3.3.2); the ascent loop
-            # is per-request (its sample count fixes the compiled shape), at a
-            # bucketed column count so repeat shapes reuse the compiled step
-            sbucket = bucket(req.num_samples, self.col_bucket_min)
-            spad = sbucket - req.num_samples
-            alpha_req = rescued.get(req.id, res.solution[:, lo:hi])
-            w_pad = jnp.pad(w_cat[:, lo:hi], ((0, 0), (0, spad)))
-            a_pad = jnp.pad(alpha_req, ((0, 0), (0, spad)))
-            post_r = PosteriorFunctions(
-                params=state.params,
-                x=state.x,
-                prior=PriorSamples(
-                    ff=state.prior.ff, w=w_pad, backend=state.prior.backend
-                ),
-                v_mean=state.post.v_mean,
-                alpha=a_pad,
-                backend=state.post.backend,
-            )
-            opts = req.options
-            pts = _maximise_samples(
-                post_r,
-                state.y,
-                ka,
-                num_candidates=int(opts.get("num_candidates", 256)),
-                num_top=int(opts.get("num_top", 2)),
-                ascent_steps=int(opts.get("ascent_steps", 10)),
-                lr=float(opts.get("lr", 1e-2)),
-                lengthscale=float(jnp.mean(state.params.lengthscale)),
-            )
-            per_sample = jnp.einsum("ss->s", post_r(pts))
-            values_by_id[req.id] = {
-                "points": pts[: req.num_samples],
-                "values": per_sample[: req.num_samples],
-            }
+            with span("thompson.ascent", request=req.id,
+                      samples=req.num_samples):
+                values_by_id[req.id] = self._thompson_ascent(
+                    req, ka, w_cat[:, lo:hi],
+                    rescued.get(req.id, res.solution[:, lo:hi]),
+                )
         values = [values_by_id.get(req.id, {}) for req in plan.requests]
         extra = dict(
             batch_columns=total,
@@ -707,6 +671,101 @@ class GPEngine:
             matvecs=matvecs,
         )
         return values, extra, errors
+
+    def _isolate_faults(self, plan, op, res, per_req, offsets, total, cbucket):
+        """Map flagged columns back to their requests, rescue each affected
+        request solo, and fail the unrescuable ones; returns ``(errors,
+        rescued)`` keyed by request id."""
+        state = self.state
+        flags = np.atleast_1d(np.asarray(jax.device_get(res.flags)))
+        if flags.size == 1 and cbucket > 1:
+            flags = np.full((cbucket,), int(flags[0]))
+        bad = (flags[:total].astype(np.int64) & FROZEN_FLAGS) != 0
+        errors: dict = {}
+        rescued: dict = {}
+        if not bad.any():
+            return errors, rescued
+        for req, (w_req, eps_req, _), lo, hi in zip(
+            plan.requests, per_req, offsets[:-1], offsets[1:]
+        ):
+            if not bad[lo:hi].any():
+                continue
+            req_flags = [int(f) for f in flags[lo:hi]]
+            names = flag_names(int(np.bitwise_or.reduce(flags[lo:hi])))
+            if self.escalation is None:
+                errors[req.id] = dict(
+                    code="solver_failure",
+                    message=(
+                        f"request {req.id} ({req.kind}) columns flagged "
+                        f"({', '.join(names)}) and rescue is disabled"
+                    ),
+                    flags=req_flags,
+                )
+                continue
+            self._stats.escalations += 1
+            data_req = state.prior.phi_mv(state.x, w_req)
+            rkey = jax.random.fold_in(
+                self._solver_key, 20_000_000 + req.id
+            )
+            report = solve_robust(
+                op,
+                data_req,
+                self.spec,
+                key=rkey,
+                delta=eps_req / state.params.noise,
+                policy=self.escalation,
+            )
+            if report.failed_columns:
+                errors[req.id] = dict(
+                    code="solver_failure",
+                    message=(
+                        f"request {req.id} ({req.kind}) columns flagged "
+                        f"({', '.join(names)}); escalation ladder "
+                        f"{report.ladder or ['(empty)']} could not recover "
+                        f"columns {report.failed_columns}"
+                    ),
+                    flags=req_flags,
+                    rungs=list(report.ladder),
+                )
+            else:
+                rescued[req.id] = report.result.solution
+        return errors, rescued
+
+    def _thompson_ascent(self, req: Request, ka, w_req, alpha_req) -> dict:
+        """THOMPSON: ascend each fresh sample path (§3.3.2); the ascent loop
+        is per-request (its sample count fixes the compiled shape), at a
+        bucketed column count so repeat shapes reuse the compiled step."""
+        state = self.state
+        sbucket = bucket(req.num_samples, self.col_bucket_min)
+        spad = sbucket - req.num_samples
+        w_pad = jnp.pad(w_req, ((0, 0), (0, spad)))
+        a_pad = jnp.pad(alpha_req, ((0, 0), (0, spad)))
+        post_r = PosteriorFunctions(
+            params=state.params,
+            x=state.x,
+            prior=PriorSamples(
+                ff=state.prior.ff, w=w_pad, backend=state.prior.backend
+            ),
+            v_mean=state.post.v_mean,
+            alpha=a_pad,
+            backend=state.post.backend,
+        )
+        opts = req.options
+        pts = _maximise_samples(
+            post_r,
+            state.y,
+            ka,
+            num_candidates=int(opts.get("num_candidates", 256)),
+            num_top=int(opts.get("num_top", 2)),
+            ascent_steps=int(opts.get("ascent_steps", 10)),
+            lr=float(opts.get("lr", 1e-2)),
+            lengthscale=float(jnp.mean(state.params.lengthscale)),
+        )
+        per_sample = jnp.einsum("ss->s", post_r(pts))
+        return {
+            "points": pts[: req.num_samples],
+            "values": per_sample[: req.num_samples],
+        }
 
     # ------------------------------------------------------------------- state
 
@@ -738,43 +797,62 @@ class GPEngine:
         Every path re-keys ``hypers_key`` (it covers n), purges the now
         unreachable warm-cache entries (counted in ``cache_purged``) and
         resets the warm-batch cold-iteration reference.
+
+        Spans: ``gp.update`` around all of it, holding the drain's
+        ``gp.step`` spans and one path child, ``gp.update.full`` or
+        ``gp.update.lowrank`` (a compaction's refit is a ``gp.update.full``
+        inside the latter); one ``gp.counters`` event after it.
         """
         update = self.update_policy if update is None else update
         if update not in ("lowrank", "full", "auto"):
             raise ValueError(
                 f"update must be 'lowrank', 'full' or 'auto', got {update!r}"
             )
-        self.run_until_idle()
+        k = int(np.shape(x_new)[0]) if np.ndim(x_new) > 1 else 1
+        with self._stats.span("update", policy=update, k=k):
+            self.run_until_idle()
+            self._update(x_new, y_new, update, warm)
+        self._stats.emit_counters()
+
+    def _update(self, x_new, y_new, update: str, warm: bool) -> None:
         skey = jax.random.fold_in(self._solver_key, 10_000_000 + self._stats.refits)
         if update == "full":
-            self._refit_full(x_new, y_new, skey, warm=warm)
+            with self._stats.span("update.full"):
+                self._refit_full(x_new, y_new, skey, warm=warm)
         else:
-            cand = update_state_lowrank(self.state, x_new, y_new, skey)
-            drift = float(jnp.max(cand.fit_result.rel_residual))
-            tol = float(getattr(self.spec, "tol", 1e-2))
-            accept = update == "lowrank" or (
-                bool(cand.fit_result.healthy)
-                and drift <= self.compaction_tol_factor * tol
-            )
-            if accept:
-                k = int(cand.n) - int(self.state.n)
-                self.state = cand
-                self._stats.lowrank_updates += 1
-                self._stats.lowrank_rows += k
-                self._stats.lowrank_iterations += int(cand.fit_result.iterations)
-                self._stats.lowrank_matvecs += int(cand.fit_result.matvecs)
-                self._stats.last_refit_rel_residual = drift
-            else:
-                # compaction: the correction drifted past the certifiable
-                # budget (or its solve flagged) — re-solve the extended system
-                # in full, warm-started from the PRE-update state
-                self._stats.compactions += 1
-                self._refit_full(x_new, y_new, skey, warm=True)
+            with self._stats.span("update.lowrank"):
+                self._update_lowrank(x_new, y_new, update, skey)
         self._stats.refits += 1
         # a new operator shape: cold-iteration reference resets with it, and
         # warm-cache entries under the superseded hypers_key are unreachable
         self._last_cold_iters = None
         self._stats.cache_purged += self.cache.purge(self.state.hypers_key)
+
+    def _update_lowrank(self, x_new, y_new, update: str, skey) -> None:
+        """The rank-k path; under ``auto``, compacted to a full warm refit
+        when the certified drift exceeds its budget."""
+        cand = update_state_lowrank(self.state, x_new, y_new, skey)
+        drift = float(jnp.max(cand.fit_result.rel_residual))
+        tol = float(getattr(self.spec, "tol", 1e-2))
+        accept = update == "lowrank" or (
+            bool(cand.fit_result.healthy)
+            and drift <= self.compaction_tol_factor * tol
+        )
+        if accept:
+            k = int(cand.n) - int(self.state.n)
+            self.state = cand
+            self._stats.lowrank_updates += 1
+            self._stats.lowrank_rows += k
+            self._stats.lowrank_iterations += int(cand.fit_result.iterations)
+            self._stats.lowrank_matvecs += int(cand.fit_result.matvecs)
+            self._stats.last_refit_rel_residual = drift
+        else:
+            # compaction: the correction drifted past the certifiable
+            # budget (or its solve flagged) — re-solve the extended system
+            # in full, warm-started from the PRE-update state
+            self._stats.compactions += 1
+            with self._stats.span("update.full"):
+                self._refit_full(x_new, y_new, skey, warm=True)
 
     def _refit_full(self, x_new, y_new, skey, *, warm: bool) -> None:
         """Full row-extension refit + its iteration/savings accounting."""

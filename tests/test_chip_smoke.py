@@ -72,12 +72,13 @@ def test_serving_phase_tiny(smoke, pallas_auto):
     x = jax.random.normal(key, (192, 3))
     y = jnp.sin(x.sum(-1)) + 0.1 * jax.random.normal(jax.random.PRNGKey(1), (192,))
     params = make_params("matern32", lengthscale=1.0, signal=1.0, noise=0.1, d=3)
-    phases = smoke.Phases(smoke.CompileMonitor())
-    out = smoke.serving_phase(x, y, x[:4] + 0.05, y[:4], params, phases, seed=0,
+    out = smoke.serving_phase(x, y, x[:4] + 0.05, y[:4], params, seed=0,
                               num_samples=4, requests=16, parity_rows=32)
     assert out["counters"]["gram"]["pallas"] > 0
     assert out["stats"]["warm_hits"] > 0
     assert out["parity"] <= smoke.PARITY_BOUND
+    assert out["stats"]["phases"]["step"]["calls"] == out["stats"]["steps"]
+    assert isinstance(smoke.report_jit(), dict)
 
 
 def test_sharded_phase_four_virtual_devices():
@@ -100,8 +101,7 @@ def test_sharded_phase_four_virtual_devices():
         params = make_params("matern32", lengthscale=0.5, signal=1.0,
                              noise=0.1, d=3)
         mesh = jax.make_mesh((4,), ("data",))
-        out = smoke.sharded_phase(x, y, params, mesh,
-                                  smoke.Phases(smoke.CompileMonitor()), iters=5)
+        out = smoke.sharded_phase(x, y, params, mesh, iters=5)
         print(json.dumps({{k: out[k] for k in ("held", "diffs", "mv_errs")}}))
     """)
     header = (
