@@ -17,6 +17,7 @@ docs/features.md).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -34,6 +35,10 @@ class ThompsonState:
     best: float
 
 
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_candidates", "num_top", "ascent_steps", "exploit_frac"),
+)
 def _maximise_samples(
     post: PosteriorFunctions,
     y: jax.Array,
@@ -46,7 +51,15 @@ def _maximise_samples(
     exploit_frac: float = 0.9,
     lengthscale: float = 0.2,
 ) -> jax.Array:
-    """Maximise each posterior sample on [0,1]^d → (s, d) acquisition points."""
+    """Maximise each posterior sample on [0,1]^d → (s, d) acquisition points.
+
+    One compiled program per shape: candidate draws, candidate evaluation,
+    top-k, the Adam scan and the final argmax all run inside this ``jit``.
+    ``post`` is a traced argument (its arrays are leaves, its backends static),
+    as are ``lr`` and ``lengthscale``; the program is keyed by the input
+    shapes (n, d, features, samples) and the static ``num_candidates``,
+    ``num_top``, ``ascent_steps`` and ``exploit_frac``.
+    """
     d = post.x.shape[1]
     s = post.num_samples
     ku, ke, kp = jax.random.split(key, 3)

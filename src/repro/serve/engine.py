@@ -732,9 +732,13 @@ class GPEngine:
         return errors, rescued
 
     def _thompson_ascent(self, req: Request, ka, w_req, alpha_req) -> dict:
-        """THOMPSON: ascend each fresh sample path (§3.3.2); the ascent loop
-        is per-request (its sample count fixes the compiled shape), at a
-        bucketed column count so repeat shapes reuse the compiled step."""
+        """THOMPSON: ascend each fresh sample path (§3.3.2), one ascent per
+        request. The whole multi-start ascent is one jitted program
+        (:func:`~repro.core.thompson._maximise_samples`), keyed by the state's
+        shapes, the bucketed sample count and the integer options
+        (``num_candidates``, ``num_top``, ``ascent_steps``); ``lr`` and the
+        lengthscale are traced, so repeat requests reuse it. The per-sample
+        values at the returned points are evaluated eagerly."""
         state = self.state
         sbucket = bucket(req.num_samples, self.col_bucket_min)
         spad = sbucket - req.num_samples
@@ -759,7 +763,7 @@ class GPEngine:
             num_top=int(opts.get("num_top", 2)),
             ascent_steps=int(opts.get("ascent_steps", 10)),
             lr=float(opts.get("lr", 1e-2)),
-            lengthscale=float(jnp.mean(state.params.lengthscale)),
+            lengthscale=jnp.mean(state.params.lengthscale),
         )
         per_sample = jnp.einsum("ss->s", post_r(pts))
         return {
