@@ -127,27 +127,29 @@ def _stationary_apply(d2: jax.Array, kind: str) -> jax.Array:
     return out.reshape(-1, m)[:n]
 
 
+def tanimoto(x: jax.Array, z: jax.Array) -> jax.Array:
+    """The Tanimoto (MinMax) kernel over non-negative count vectors (Ch. 4
+    molecules): ``T(x, z) = Σᵢ min(xᵢ, zᵢ) / Σᵢ max(xᵢ, zᵢ)``, with
+    ``Σ max = |x|₁ + |z|₁ − Σ min``; 0 between two all-zero vectors. On binary
+    inputs it is the Jaccard form ``x·z / (|x|² + |z|² − x·z)``."""
+    smin = jnp.sum(jnp.minimum(x[:, None, :], z[None, :, :]), axis=-1)
+    smax = jnp.sum(x, axis=-1)[:, None] + jnp.sum(z, axis=-1)[None, :] - smin
+    return smin / jnp.where(smax > 0, smax, 1.0)
+
+
 def gram(params: KernelParams, x: jax.Array, z: Optional[jax.Array] = None) -> jax.Array:
     """Dense Gram matrix K(x, z) — the reference path (O(n m) memory)."""
     z = x if z is None else z
     if params.kind == TANIMOTO:
-        # Tanimoto/Jaccard over non-negative count vectors (Ch. 4 molecules):
-        # T(x,z) = <min(x,z)> / <max(x,z)> = (via counts) s / (|x|+|z| - s), s = Σ min.
-        # For binary/count fingerprints with x,z >= 0: Σ min(x_i,z_i) has no matmul
-        # form in general; use the standard inner-product form valid for binary data.
-        inner = x @ z.T
-        xn = jnp.sum(x * x, axis=-1)[:, None]
-        zn = jnp.sum(z * z, axis=-1)[None, :]
-        denom = xn + zn - inner
-        return params.signal * inner / jnp.maximum(denom, 1e-12)
+        return params.signal * tanimoto(x, z)
     ls = params.lengthscale
     d2 = _sqdist(x / ls, z / ls)
     return params.signal * _stationary_apply(d2, params.kind)
 
 
 def gram_diag(params: KernelParams, x: jax.Array) -> jax.Array:
-    if params.kind == TANIMOTO:
-        return params.signal * jnp.ones(x.shape[0], x.dtype)
+    if params.kind == TANIMOTO:  # T(x, x) = 1, but 0 for an all-zero vector
+        return params.signal * (jnp.sum(x, axis=-1) > 0).astype(x.dtype)
     return params.signal * jnp.ones(x.shape[0], x.dtype)
 
 

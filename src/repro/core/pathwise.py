@@ -63,12 +63,21 @@ class PosteriorFunctions:
             self.params, xs, w, z=self.x, backend=self.backend
         )
 
-    def sample_mean_and_var(self, xs: jax.Array) -> tuple[jax.Array, jax.Array]:
-        f = self(xs)
-        return self.mean(xs), jnp.var(f, axis=1)
+    def sample_mean_and_var(
+        self, xs: jax.Array, f_prior: Optional[jax.Array] = None
+    ) -> tuple[jax.Array, jax.Array]:
+        """Mean and sample variance at xs. One cross-covariance matvec serves
+        both, over the columns ``[v_mean | v_mean − alpha]``; ``f_prior`` is
+        ``self.prior(xs)`` when the caller has evaluated it already."""
+        f_prior = self.prior(xs) if f_prior is None else f_prior
+        cols = jnp.concatenate(
+            [self.v_mean[:, None], self.v_mean[:, None] - self.alpha], axis=1
+        )
+        k = gram_mv(self.params, xs, cols, z=self.x, backend=self.backend)
+        return k[:, 0], jnp.var(f_prior + k[:, 1:], axis=1)
 
     def blocked_mean_and_var(
-        self, xs_blocks: jax.Array
+        self, xs_blocks: jax.Array, f_prior: Optional[jax.Array] = None
     ) -> tuple[jax.Array, jax.Array]:
         """Batched query path: many query blocks through ONE fused pass.
 
@@ -78,10 +87,11 @@ class PosteriorFunctions:
         cross-covariance matvec and one prior feature matvec serve all B
         queries, and the results are reshaped back to ``(B, L)`` mean and
         variance. Padding rows cost flops but not correctness — callers slice
-        their valid prefix.
+        their valid prefix. ``f_prior``: the prior paths at the flattened rows,
+        if the caller evaluated them.
         """
         b, l, d = xs_blocks.shape
-        mean, var = self.sample_mean_and_var(xs_blocks.reshape(b * l, d))
+        mean, var = self.sample_mean_and_var(xs_blocks.reshape(b * l, d), f_prior)
         return mean.reshape(b, l), var.reshape(b, l)
 
     def sample_paths(

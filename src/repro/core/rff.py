@@ -1,4 +1,4 @@
-"""Random Fourier features (§2.2.2): approximate prior function samples.
+"""Random features (§2.2.2): approximate prior function samples.
 
 A prior sample is f(x) ≈ Φ(x) w with w ~ N(0, I), Φ(x)_j = sqrt(2σ_f²/m) cos(ω_jᵀx+b_j),
 or the lower-variance paired sin/cos form (Sutherland & Schneider, 2015). Pathwise
@@ -16,6 +16,13 @@ is differentiable w.r.t. inputs, frequencies, weights and σ_f². The historical
 the default everywhere, so Thompson sampling's Adam ascent and the SGD regulariser
 gradient run fused end to end on TPU and fall back to materialised features on CPU.
 See docs/features.md.
+
+The Tanimoto kernel is not stationary and has no spectral density, so its prior
+samples use Tanimoto random features instead (:class:`TanimotoFeatures`; Tripp,
+Bacallado, Singh and Hernández-Lobato, NeurIPS 2023): each feature is the random
+sign of a consistent weighted-sampling hash of the count vector (Ioffe 2010),
+two vectors' hashes collide with probability exactly T(x, z), so
+E[φ(x)φ(z)] = T(x, z). :func:`sample_prior` picks the map by kernel kind.
 """
 from __future__ import annotations
 
@@ -25,7 +32,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .kernels_fn import KernelParams, spectral_sample
+from .kernels_fn import TANIMOTO, KernelParams, spectral_sample
 from .operators import FeatureOperator, shard_map_over
 
 
@@ -227,6 +234,92 @@ def make_fourier_features(
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
+class TanimotoFeatures(FeatureOperator):
+    """Tanimoto random features: a :class:`FeatureOperator` whose k-th feature
+    of a count vector x is ``sqrt(σ_f² / m) · s_k(h_k(x))``.
+
+    ``h_k(x) = (i*, t*)`` is Ioffe's consistent weighted-sampling hash over the
+    non-zero dims of x: per dim, ``t = ⌊ln x_i / r + β⌋``, ``y = e^{r (t − β)}``,
+    ``a = c / (y e^r)`` from the draws ``r``, ``c`` ~ Gamma(2, 1) and ``β`` ~
+    U(0, 1) (each (m, d)); ``i* = argmin a`` and ``t*`` its ``t``. Two vectors'
+    hashes collide with probability T(x, z) (MinMax). ``s_k`` is the random
+    sign ``1 − 2 · hash_bit(salt_k, i*, t*)`` (kernels/tanimoto.py), so
+    E[φ(x)φ(z)] = σ_f² T(x, z); an all-zero vector has zero features.
+
+    The draws and ``salt`` are the map itself (exposed like ``omega`` of
+    :class:`FourierFeatures`); ``table`` is their (C, m, d) tabulation for
+    counts 1..C that the kernels read (``tanimoto.rf_table``: Ioffe's ``a``
+    with the hash's sign). Inputs must be integer counts 0..``MAX_COUNT``
+    (kernels/tanimoto.py); a row with anything else evaluates to NaN.
+    ``phi_mv`` dispatches through ``ops.tanimoto_rf_mv`` (``pallas``: signs
+    built in VMEM; ``features``: materialised); there is no derivative with
+    respect to x.
+    """
+
+    r: jax.Array  # (m, d) Gamma(2, 1)
+    c: jax.Array  # (m, d) Gamma(2, 1)
+    beta: jax.Array  # (m, d) U(0, 1)
+    salt: jax.Array  # (m,) uint32, the sign function of each feature
+    table: jax.Array  # (MAX_COUNT, m, d)
+    signal: jax.Array  # σ_f²
+    backend: str = dataclasses.field(default="auto", metadata=dict(static=True))
+    precision: str = dataclasses.field(default="fp32", metadata=dict(static=True))
+
+    @property
+    def num_features(self) -> int:
+        return self.r.shape[0]
+
+    def with_backend(self, backend: str) -> "TanimotoFeatures":
+        return dataclasses.replace(self, backend=backend)
+
+    def features(self, x: jax.Array) -> jax.Array:
+        """Φ(x) materialised: (n, m)."""
+        from ..kernels.tanimoto import rf_signs  # deferred: pallas import
+
+        scale = jnp.sqrt(self.signal / self.num_features)
+        return scale * rf_signs(x, self.table)
+
+    def phi_mv(self, x: jax.Array, w: jax.Array, *, backend: Optional[str] = None,
+               precision: Optional[str] = None) -> jax.Array:
+        """Φ(x) @ w: (n, s-like)."""
+        from ..kernels.ops import tanimoto_rf_mv  # deferred: pallas import
+
+        return tanimoto_rf_mv(
+            x, self.table, w, signal=self.signal,
+            backend=self.backend if backend is None else backend,
+            precision=precision or self.precision,
+        )
+
+    def phi_t_mv(self, x: jax.Array, u: jax.Array, *, backend: Optional[str] = None,
+                 precision: Optional[str] = None) -> jax.Array:
+        """Φ(x)ᵀ @ u: (m, s-like), through the materialised features."""
+        from ..kernels.ops import FEATURE_TRACE_COUNTS, resolve_feature_backend
+
+        resolve_feature_backend(self.backend if backend is None else backend)
+        FEATURE_TRACE_COUNTS["features"] += 1  # materialises Φ below
+        return self.features(x).T @ u
+
+
+def make_tanimoto_features(
+    params: KernelParams, key: jax.Array, num_features: int, d: int
+) -> TanimotoFeatures:
+    """Draw ``num_features`` Tanimoto random features over d-dim counts."""
+    from ..kernels.tanimoto import rf_table  # deferred: pallas import
+
+    kr, kc, kb, ks = jax.random.split(key, 4)
+    shape = (num_features, d)
+    r = jax.random.gamma(kr, 2.0, shape)
+    c = jax.random.gamma(kc, 2.0, shape)
+    beta = jax.random.uniform(kb, shape)
+    salt = jax.random.bits(ks, (num_features,), jnp.uint32)
+    return TanimotoFeatures(
+        r=r, c=c, beta=beta, salt=salt,
+        table=rf_table(r, c, beta, salt), signal=params.signal,
+    )
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
 class PriorSamples(FeatureOperator):
     """s prior function samples f^(i)(·) = Φ(·) w_i, evaluable anywhere.
 
@@ -240,7 +333,7 @@ class PriorSamples(FeatureOperator):
     ``"fused"``) forces the fused kernel (interpret mode off-TPU).
     """
 
-    ff: FourierFeatures
+    ff: FeatureOperator  # FourierFeatures or TanimotoFeatures
     w: jax.Array  # (num_features, s)
     backend: str = dataclasses.field(default="auto", metadata=dict(static=True))
 
@@ -278,7 +371,13 @@ def sample_prior(
     num_features: int,
     d: int,
 ) -> PriorSamples:
+    """``num_samples`` prior paths: random Fourier features for the stationary
+    kinds, Tanimoto random features (``num_features`` hashes) for
+    ``tanimoto``."""
     kf, kw = jax.random.split(key)
-    ff = make_fourier_features(params, kf, num_features, d)
+    if params.kind == TANIMOTO:
+        ff = make_tanimoto_features(params, kf, num_features, d)
+    else:
+        ff = make_fourier_features(params, kf, num_features, d)
     w = jax.random.normal(kw, (ff.num_features, num_samples))
     return PriorSamples(ff=ff, w=w)

@@ -37,7 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 _SQRT3 = 1.7320508075688772
 _SQRT5 = 2.23606797749979
 
-# kernel kinds the fused Pallas path supports (tanimoto has no distance form)
+# kernel kinds of this distance-as-matmul kernel; tanimoto has its own (tanimoto.py)
 PALLAS_KINDS = ("se", "matern12", "matern32", "matern52")
 
 #: Tile-operand precisions. ``"fp32"`` is the default everywhere; ``"bf16"``

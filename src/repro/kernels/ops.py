@@ -1,31 +1,35 @@
 """Jit'd public wrappers around the Pallas kernels — and the library's matvec
-backend-selection layer, for Gram *and* feature-map (RFF) contractions.
+backend-selection layer, for Gram *and* feature-map contractions.
 
 Every Gram-matvec in the library routes through :func:`gram_mv` (full matvecs) or
 :func:`gram_rows_matvec` (row-block matvecs), which dispatch on a ``backend``
 string:
 
-* ``"pallas"``  — the fused, differentiable Pallas kernel (gram_matvec.py):
-  K tiles built in VMEM, never materialised in HBM. Compiled on TPU, interpret
-  mode elsewhere. Raises for kernels without a distance-as-matmul form
-  (``tanimoto``).
+* ``"pallas"``  — the fused, Pallas kernel of the kernel kind, K tiles built in
+  VMEM and never materialised in HBM: ``gram_matvec.py`` for the stationary
+  kinds (differentiable throughout), ``tanimoto.py`` for ``tanimoto`` (exact on
+  integer counts 0..``tanimoto.MAX_COUNT``, Σmin on the MXU through count planes;
+  no derivative with respect to the points). Compiled on TPU, interpret mode
+  elsewhere.
 * ``"chunked"`` — the pure-JAX row-chunked matvec (core/kernels_fn.py):
   O(chunk·m) memory, any kernel kind, autodiff throughout.
 * ``"dense"``   — materialise K and multiply (small-n reference / tests).
 * ``"auto"``    — Pallas when running on TPU (interpret mode is slower than
-  chunked XLA on CPU), chunked otherwise; always chunked for ``tanimoto``.
+  chunked XLA on CPU), chunked otherwise, for every kind.
 
 Every feature-map matvec routes through :func:`rff_mv` (Φ(x) @ w) or
-:func:`rff_t_mv` (Φ(x)ᵀ @ u) — the ``FeatureOperator`` twins of ``gram_mv``,
-dispatching on ``"pallas"`` (fused, (n × 2m) feature matrix never in HBM) /
-``"features"`` (materialise Φ and matmul) / ``"auto"``. The Gram backend names
-``"chunked"``/``"dense"`` coerce to ``"features"``, so one spec-level ``backend``
-field pins both sides of a solve.
+:func:`rff_t_mv` (Φ(x)ᵀ @ u) for random Fourier features and
+:func:`tanimoto_rf_mv` for Tanimoto random features — the ``FeatureOperator``
+twins of ``gram_mv``, dispatching on ``"pallas"`` (fused, (n × F) feature
+matrix never in HBM) / ``"features"`` (materialise Φ and matmul) / ``"auto"``.
+The Gram backend names ``"chunked"``/``"dense"`` coerce to ``"features"``, so
+one spec-level ``backend`` field pins both sides of a solve.
 
-All paths are differentiable w.r.t. the hyperparameters: the Pallas paths wrap
-``jax.custom_vjp``\\ s whose backward passes are themselves fused Pallas
-contractions, with σ_f², lengthscale and jitter folded in *outside* the
-custom-VJP cores so their gradients flow through ordinary autodiff.
+All stationary paths are differentiable w.r.t. the hyperparameters: the Pallas
+paths wrap ``jax.custom_vjp``\\ s whose backward passes are themselves fused
+Pallas contractions, with σ_f², lengthscale and jitter folded in *outside* the
+custom-VJP cores so their gradients flow through ordinary autodiff (σ_f² and
+the jitter likewise for ``tanimoto``).
 
 ``MATVEC_TRACE_COUNTS`` / ``FEATURE_TRACE_COUNTS`` record how many Gram/feature
 matvecs each backend dispatched (counted when the op is staged, i.e. per trace or
@@ -45,9 +49,20 @@ from .gram_matvec import (
     gram_rows_pair_fused,
 )
 from .rff_matvec import rff_matvec_fused, rff_pair_fused, rff_t_matvec_fused
+from .tanimoto import ABSENT, rf_signs, tanimoto_matvec_fused, tanimoto_rf_pallas
 from .flash_attention import flash_attention_pallas
 
 BACKENDS = ("auto", "pallas", "chunked", "dense")
+
+#: kernel kinds with a fused Pallas matvec
+FUSED_KINDS = PALLAS_KINDS + ("tanimoto",)
+
+#: Tanimoto tiles: (512, 1024) float32 count tiles keep the MXU busy with
+#: C·1024-deep plane products; smaller operands take one tile of their own
+#: size (rounded up to 16 rows, the bfloat16 plane tile)
+TANIMOTO_BLOCK = 512
+#: Tanimoto random features: rows and hashes per grid step
+TANIMOTO_RF_BLOCK = 256
 
 #: Tile/contration precisions (re-exported from gram_matvec): ``"fp32"``
 #: everywhere by default; ``"bf16"`` casts contraction operands to bfloat16
@@ -91,22 +106,18 @@ def resolve_backend(backend: str = "auto", kind: str = "se") -> str:
     """Normalise a backend request to a concrete backend for kernel ``kind``.
 
     ``auto`` picks the fused Pallas kernel on TPU and the chunked JAX matvec
-    elsewhere, and silently falls back to chunked for kinds the Pallas kernel
-    cannot express (``tanimoto`` has no distance-as-matmul form). Requesting
-    ``pallas`` explicitly for such a kind is an error.
+    elsewhere. Every kind in ``FUSED_KINDS`` has a Pallas kernel; any other
+    kind is refused by ``pallas`` and ``auto`` alike, never rerouted.
     """
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; expected one of {BACKENDS}")
-    if backend == "auto":
-        if kind not in PALLAS_KINDS:
-            return "chunked"
-        return "pallas" if _on_tpu() else "chunked"
-    if backend == "pallas" and kind not in PALLAS_KINDS:
+    if backend in ("auto", "pallas") and kind not in FUSED_KINDS:
         raise ValueError(
-            f"kernel kind {kind!r} is not supported by the fused Pallas backend "
-            f"(no distance-as-matmul form); supported kinds: {PALLAS_KINDS}. "
-            f"Use backend='chunked', or backend='auto' to fall back automatically."
+            f"kernel kind {kind!r} has no fused Pallas matvec; supported kinds: "
+            f"{FUSED_KINDS}. Use backend='chunked'."
         )
+    if backend == "auto":
+        return "pallas" if _on_tpu() else "chunked"
     return backend
 
 
@@ -148,7 +159,28 @@ def _dot(a: jax.Array, b: jax.Array, precision: str) -> jax.Array:
     return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
+def _tile(block, rows: int) -> int:
+    """``block`` rows, or one tile of the operand's own rows (rounded up to 16)
+    when it has fewer."""
+    return min(int(block), -(-rows // 16) * 16)
+
+
+def _pallas_tanimoto_mv(params, x, v2, z, block, interpret, precision):
+    interpret = (not _on_tpu()) if interpret is None else interpret
+    block = TANIMOTO_BLOCK if block == "auto" else block
+    n = x.shape[0]
+    bm = _tile(block, n)
+    bn = bm if z is None else _tile(block, z.shape[0])
+    xp = _pad_rows(x, bm)
+    zp = xp if z is None else _pad_rows(z, bn)
+    vp = _pad_rows(v2, bn)
+    out = tanimoto_matvec_fused(bm, bn, bool(interpret), precision, xp, zp, vp)
+    return params.signal * out[:n]
+
+
 def _pallas_gram_mv(params, x, v2, z, block, interpret, precision="fp32"):
+    if params.kind == "tanimoto":
+        return _pallas_tanimoto_mv(params, x, v2, z, block, interpret, precision)
     interpret = (not _on_tpu()) if interpret is None else interpret
     ls = params.lengthscale
     xs = x / ls
@@ -280,6 +312,11 @@ def gram_rows_pair(
     _check_precision(precision)
     MATVEC_TRACE_COUNTS[bk] += 2
     xi = x[idx]
+    if bk == "pallas" and params.kind == "tanimoto":
+        # no two-phase Tanimoto kernel: the two fused matvecs in sequence
+        kw = dict(block=block, interpret=interpret, precision=precision)
+        err = _pallas_tanimoto_mv(params, xi, look, x, **kw) - b
+        return err, _pallas_tanimoto_mv(params, x, err, xi, **kw)
     if bk == "pallas":
         interpret = (not _on_tpu()) if interpret is None else interpret
         ls = params.lengthscale
@@ -498,6 +535,50 @@ def rff_pair_mv(
     else:
         feats = _materialised_features(x, omega, signal)  # built once, used twice
         out = _dot(feats, _dot(feats.T, u2, precision), precision)
+    return out[:, 0] if squeeze else out
+
+
+def tanimoto_rf_mv(
+    x: jax.Array,
+    table: jax.Array,
+    w: jax.Array,
+    *,
+    signal=1.0,
+    backend: str = "auto",
+    block="auto",
+    interpret=None,
+    precision: str = "fp32",
+) -> jax.Array:
+    """Φ(x) @ w for Tanimoto random features (core/rff.py
+    ``TanimotoFeatures``): x:(n,d) counts, table:(C,m,d) from
+    ``tanimoto.rf_table``, w:(m,) or (m,s) → (n, s-like). ``pallas`` runs the
+    fused ``tanimoto_rf_pallas`` kernel (signs built in VMEM); ``features``
+    materialises the (n, m) sign matrix. ``precision="bf16"`` rounds ``w`` to
+    bfloat16 before the contraction on both."""
+    bk = resolve_feature_backend(backend)
+    _check_precision(precision)
+    FEATURE_TRACE_COUNTS[bk] += 1
+    squeeze = w.ndim == 1
+    w2 = w[:, None] if squeeze else w
+    m = table.shape[1]
+    if bk == "pallas":
+        interpret = (not _on_tpu()) if interpret is None else interpret
+        block = TANIMOTO_RF_BLOCK if block == "auto" else block
+        n = x.shape[0]
+        bm, bq = _tile(block, n), _tile(block, m)
+        if precision == "bf16":
+            w2 = w2.astype(jnp.bfloat16).astype(jnp.float32)
+        pad_k = (-m) % bq
+        if pad_k:  # padded hashes hold no finite entry: their signs decode to 0
+            table = jnp.pad(table, ((0, 0), (0, pad_k), (0, 0)),
+                            constant_values=ABSENT)
+        out = tanimoto_rf_pallas(
+            _pad_rows(x, bm), table, _pad_rows(w2, bq), block_m=bm, block_k=bq,
+            interpret=bool(interpret),
+        )[:n]
+    else:
+        out = _dot(rf_signs(x, table), w2, precision)
+    out = out * jnp.sqrt(signal / m)
     return out[:, 0] if squeeze else out
 
 

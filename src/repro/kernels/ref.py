@@ -48,6 +48,18 @@ def gram_matvec_ref(
     return out
 
 
+def tanimoto_matvec_ref(
+    x: jax.Array, z: jax.Array, v: jax.Array, *, signal: float = 1.0
+) -> jax.Array:
+    """signal·T(x, z) @ v for the Tanimoto (MinMax) kernel on non-negative
+    counts, from its definition: T = Σ min(x, z) / Σ max(x, z), 0 between two
+    all-zero vectors. x:(n,d) z:(m,d) v:(m,s) → (n,s)."""
+    smin = jnp.sum(jnp.minimum(x[:, None, :], z[None, :, :]), axis=-1)
+    smax = jnp.sum(jnp.maximum(x[:, None, :], z[None, :, :]), axis=-1)
+    t = jnp.where(smax > 0, smin / jnp.where(smax > 0, smax, 1.0), 0.0)
+    return signal * t @ v
+
+
 def rff_matvec_ref(
     x: jax.Array, omega: jax.Array, w: jax.Array, *, signal: float = 1.0
 ) -> jax.Array:

@@ -43,13 +43,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.kernels_fn import KernelParams
+from ..core.kernels_fn import TANIMOTO, KernelParams
 from ..core.pathwise import PosteriorFunctions
 from ..core.rff import PriorSamples
 from ..core.solvers.base import FROZEN_FLAGS, flag_names
 from ..core.solvers.robust import EscalationPolicy, _pin_backend, solve_robust
 from ..core.solvers.spec import SpecLike, as_spec, solve
 from ..core.thompson import _maximise_samples
+from ..kernels.tanimoto import check_counts
 from .metrics import EngineStats
 from .request import (
     Completion,
@@ -97,6 +98,11 @@ class GPEngine:
             count and prior feature count (predict variance quality).
         max_batch_requests / max_rhs_columns: scheduler caps.
         row_bucket_min / col_bucket_min: smallest padded block shapes.
+        predict_split_rows: a predict request whose row bucket reaches this
+            many rows is computed in a pass of its own bucket, beside one pass
+            for the batch's smaller requests, so it never pads them to its
+            size; ``None`` (the default): one pass, every block padded to the
+            batch's largest request.
         clock: timeline source for arrival/latency stamps (injectable so the
             benchmark can drive a simulated arrival process); compute durations
             are always measured with ``time.perf_counter``.
@@ -146,6 +152,7 @@ class GPEngine:
         max_rhs_columns: int = 64,
         row_bucket_min: int = 16,
         col_bucket_min: int = 8,
+        predict_split_rows: Optional[int] = None,
         warm_cache_entries: int = 256,
         default_sample_count: int = 8,
         clock: Callable[[], float] = time.monotonic,
@@ -171,12 +178,17 @@ class GPEngine:
                 f"update_policy must be 'lowrank', 'full' or 'auto', got "
                 f"{update_policy!r}"
             )
+        if predict_split_rows is not None and predict_split_rows < 1:
+            raise ValueError(
+                f"predict_split_rows must be None or >= 1, got {predict_split_rows!r}"
+            )
         self.update_policy = update_policy
         self.compaction_tol_factor = float(compaction_tol_factor)
         self.spec = as_spec(spec)
         self._clock = clock
         self.row_bucket_min = int(row_bucket_min)
         self.col_bucket_min = int(col_bucket_min)
+        self.predict_split_rows = predict_split_rows
         self.default_sample_count = int(default_sample_count)
         self.default_deadline_s = default_deadline_s
         self.max_queue_depth = max_queue_depth
@@ -193,7 +205,9 @@ class GPEngine:
         self.state: PosteriorState = fit_state(
             params, x, y, kf,
             spec=self.spec, num_samples=num_samples, num_features=num_features,
+            span=self._stats.span,
         )
+        self._stats.feature_rows += self.state.n
         self.scheduler = FIFOScheduler(
             max_batch_requests=max_batch_requests,
             max_rhs_columns=max_rhs_columns,
@@ -243,7 +257,10 @@ class GPEngine:
         rejects everything else; policy ``"reject"`` refuses all submits —
         rejection raises :class:`EngineOverloaded` as backpressure. A
         quarantined (kind, seed) identity completes immediately with a
-        ``quarantined`` error.
+        ``quarantined`` error. A ``thompson_step`` on a ``tanimoto`` posterior
+        completes immediately with an ``unsupported_kernel`` error: its
+        continuous ascent has no meaning on count fingerprints. Its query
+        blocks must be integer counts 0..``MAX_COUNT`` (``ValueError``).
         """
         with self._stats.span("submit", kind=kind):
             return self._submit(kind, xs, num_samples, seed, deadline_s, options)
@@ -270,9 +287,12 @@ class GPEngine:
                     f"{self.max_queue_depth}; request shed "
                     f"(policy={self.overload_policy!r}) — back off and retry"
                 )
+        tanimoto = self.state.params.kind == TANIMOTO
         if kind in (PREDICT, SAMPLE):
             if xs is None:
                 raise ValueError(f"{kind!r} requests need a query block xs of shape (m, d)")
+            if tanimoto:
+                check_counts(xs)
             xs = jnp.atleast_2d(jnp.asarray(xs))
             if xs.shape[1] != self.state.x.shape[1]:
                 raise ValueError(
@@ -309,6 +329,18 @@ class GPEngine:
         handle = RequestHandle(req)
         self._handles[req.id] = handle
         self._stats.requests_submitted += 1
+        if kind == THOMPSON and tanimoto:
+            self._fail(
+                req,
+                code="unsupported_kernel",
+                message=(
+                    "thompson_step ascends sample paths over continuous "
+                    "inputs; a tanimoto posterior is defined on integer count "
+                    "fingerprints, where that ascent has no meaning — score "
+                    "candidate fingerprints with predict or sample instead"
+                ),
+            )
+            return handle
         if kind in SOLVE_KINDS and (kind, int(seed)) in self._quarantine:
             # repeat offender: fail fast instead of poisoning another batch
             self._stats.quarantined += 1
@@ -412,8 +444,7 @@ class GPEngine:
         while True:
             try:
                 if plan.group == GROUP_PREDICT:
-                    with self._stats.span("predict"):
-                        values, extra = self._execute_predict(plan)
+                    values, extra = self._execute_predict(plan)
                     errors: dict = {}
                 else:
                     values, extra, errors = self._execute_solve(plan)
@@ -489,23 +520,53 @@ class GPEngine:
 
     # --------------------------------------------------------------- execution
 
+    def _predict_passes(self, requests) -> List[List[int]]:
+        """Indices of ``requests`` per predict pass: one pass for every request
+        below ``predict_split_rows`` and one per row bucket at or above it."""
+        split = self.predict_split_rows
+        passes: dict = {}
+        for i, req in enumerate(requests):
+            rows = bucket(req.num_rows, self.row_bucket_min)
+            passes.setdefault(rows if split and rows >= split else 0, []).append(i)
+        return [passes[k] for k in sorted(passes)]
+
     def _execute_predict(self, plan: BatchPlan):
-        """One fused row-batched mean/variance pass over cached state."""
-        d = self.state.x.shape[1]
-        rows = bucket(plan.max_rows, self.row_bucket_min)
-        nblk = bucket(len(plan.requests), 1)
-        blocks = np.zeros((nblk, rows, d), dtype=np.asarray(self.state.x).dtype)
-        for i, req in enumerate(plan.requests):
-            blocks[i, : req.num_rows] = np.asarray(req.xs)
-        mean, var = self.state.post.blocked_mean_and_var(jnp.asarray(blocks))
-        values = [
-            {"mean": mean[i, : r.num_rows], "var": var[i, : r.num_rows]}
-            for i, r in enumerate(plan.requests)
-        ]
-        real_rows = sum(r.num_rows for r in plan.requests)
-        self._stats.predict_rows += real_rows
-        self._stats.predict_padded_rows += nblk * rows - real_rows
-        return values, dict(bucket_rows=rows, bucket_blocks=nblk)
+        """Fused row-batched mean/variance passes over cached state: one per
+        group of ``_predict_passes``, each block padded to its pass's largest
+        request.
+
+        Spans, per pass: ``gp.predict`` (``kernel``, ``rows`` the requests'
+        rows, ``bucket_rows`` each block's padded rows) around it,
+        ``gp.features`` (``kind``, ``rows``) around the prior's evaluation on
+        the blocks."""
+        state = self.state
+        d = state.x.shape[1]
+        values: list = [None] * len(plan.requests)
+        extra = dict(bucket_rows=0, bucket_blocks=0)
+        for idx in self._predict_passes(plan.requests):
+            reqs = [plan.requests[i] for i in idx]
+            rows = bucket(max(r.num_rows for r in reqs), self.row_bucket_min)
+            nblk = bucket(len(reqs), 1)
+            real_rows = sum(r.num_rows for r in reqs)
+            with self._stats.span("predict", kernel=state.params.kind,
+                                  rows=real_rows, bucket_rows=rows):
+                blocks = np.zeros((nblk, rows, d), dtype=state.x.dtype)
+                for i, req in enumerate(reqs):
+                    blocks[i, : req.num_rows] = np.asarray(req.xs)
+                blocks = jnp.asarray(blocks)
+                with self._stats.span("features", kind=state.params.kind,
+                                      rows=nblk * rows):
+                    f_prior = state.post.prior(blocks.reshape(nblk * rows, d))
+                mean, var = state.post.blocked_mean_and_var(blocks, f_prior)
+                for i, (j, r) in enumerate(zip(idx, reqs)):
+                    values[j] = {"mean": mean[i, : r.num_rows],
+                                 "var": var[i, : r.num_rows]}
+            self._stats.predict_rows += real_rows
+            self._stats.predict_padded_rows += nblk * rows - real_rows
+            self._stats.feature_rows += nblk * rows
+            extra["bucket_rows"] = max(extra["bucket_rows"], rows)
+            extra["bucket_blocks"] += nblk
+        return values, extra
 
     def _request_draws(self, req: Request):
         """Deterministic per-request randomness: fresh prior weight draws and
@@ -570,6 +631,7 @@ class GPEngine:
             # (padded zero-weight columns give zero columns, which converge
             # instantly)
             data = state.prior.phi_mv(state.x, w_cat)
+            self._stats.feature_rows += n
 
         x0 = None
         if plan.group == GROUP_SOLVE_WARM:
@@ -635,6 +697,7 @@ class GPEngine:
                 xs_all = jnp.concatenate([req.xs for req, _ in sample_at], axis=0)
                 xs_pad = jnp.pad(xs_all, ((0, rbucket - r_total), (0, 0)))
                 vals = state.post.sample_paths(xs_pad, w_cat, res.solution)
+                self._stats.feature_rows += rbucket
                 for (req, lo), ro in zip(sample_at, row_offsets):
                     values_by_id[req.id] = {
                         "samples": vals[ro : ro + req.num_rows,
@@ -646,6 +709,7 @@ class GPEngine:
             # ladder)
             for req, (w_req, _, _) in zip(plan.requests, per_req):
                 if req.kind == SAMPLE and req.id in rescued:
+                    self._stats.feature_rows += req.num_rows
                     values_by_id[req.id] = {
                         "samples": state.post.sample_paths(
                             req.xs, w_req, rescued[req.id]
@@ -704,6 +768,7 @@ class GPEngine:
                 continue
             self._stats.escalations += 1
             data_req = state.prior.phi_mv(state.x, w_req)
+            self._stats.feature_rows += state.n
             rkey = jax.random.fold_in(
                 self._solver_key, 20_000_000 + req.id
             )
@@ -766,6 +831,7 @@ class GPEngine:
             lengthscale=jnp.mean(state.params.lengthscale),
         )
         per_sample = jnp.einsum("ss->s", post_r(pts))
+        self._stats.feature_rows += pts.shape[0]
         return {
             "points": pts[: req.num_samples],
             "values": per_sample[: req.num_samples],

@@ -225,6 +225,9 @@ class EngineStats:
     last_refit_rel_residual: float = 0.0  # latest update's certified drift
     predict_rows: int = 0
     predict_padded_rows: int = 0
+    # rows the engine passed to the prior's feature map (fit, solve
+    # right-hand sides, predict and sample blocks with their padding)
+    feature_rows: int = 0
     # fault-tolerance counters (docs/robustness.md): every failure-handling
     # decision the engine takes is visible here, so chaos tests and the
     # serve_gp --json driver can assert on exactly what happened
@@ -324,6 +327,7 @@ class EngineStats:
             "last_refit_rel_residual": self.last_refit_rel_residual,
             "predict_rows": self.predict_rows,
             "predict_padded_rows": self.predict_padded_rows,
+            "feature_rows": self.feature_rows,
             "deadline_misses": self.deadline_misses,
             "shed": self.shed,
             "degraded": self.degraded,

@@ -22,16 +22,17 @@ paths, and a :class:`~repro.core.solvers.spec.SolverSpec`. This module owns
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 from collections import OrderedDict
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.kernels_fn import KernelParams, gram
+from ..core.kernels_fn import TANIMOTO, KernelParams, gram
 from ..core.operators import Gram
 from ..core.pathwise import PosteriorFunctions, pathwise_target_rows
 from ..core.solvers.base import (
@@ -42,6 +43,7 @@ from ..core.solvers.base import (
 )
 from ..core.rff import PriorSamples, sample_prior
 from ..core.solvers.spec import SolverSpec, as_spec, solve, solve_bordered
+from ..kernels.tanimoto import check_counts
 
 
 def hypers_fingerprint(params: KernelParams, n: int) -> str:
@@ -107,19 +109,27 @@ def fit_state(
     num_samples: int = 16,
     num_features: int = 2048,
     x0: Optional[jax.Array] = None,
+    span: Optional[Callable] = None,
 ) -> PosteriorState:
     """Fit the engine's posterior state: one batched pathwise solve.
 
     Same math as :func:`~repro.core.pathwise.posterior_functions`, but keeps
     the noise draws ``eps`` so :func:`extend_state` can refit incrementally.
+    A ``tanimoto`` posterior refuses inputs that are not integer counts
+    0..``MAX_COUNT`` (kernels/tanimoto.py). ``span(phase, **meta)``, if given, is opened as
+    ``features`` around the prior's evaluation on x (the engine's spans).
     """
     s = as_spec(spec)
+    if params.kind == TANIMOTO:
+        check_counts(x)
     x = jnp.asarray(x)
     y = jnp.asarray(y)
     kp, ke, ks = jax.random.split(key, 3)
     op = Gram(x=x, params=params)
     prior = sample_prior(params, kp, num_samples, num_features, x.shape[1])
-    f_x = prior(x)  # (n, s)
+    span = span or (lambda phase, **meta: contextlib.nullcontext())
+    with span("features", kind=params.kind, rows=int(x.shape[0])):
+        f_x = prior(x)  # (n, s)
     data, delta, eps = pathwise_target_rows(op.noise, y, f_x, ke)
     res = solve(op, data, s, key=ks, x0=x0, delta=delta)
     sol = res.solution

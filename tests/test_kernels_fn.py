@@ -63,6 +63,12 @@ def test_tanimoto_bounds_and_identity():
     assert float(k.min()) >= 0.0 and float(k.max()) <= 1.0 + 1e-6
     nz = np.asarray(x.sum(1) > 0)
     np.testing.assert_allclose(np.diag(k)[nz], 1.0, atol=1e-6)
+    # counts: MinMax, against a brute-force min/max sum
+    counts = np.asarray(x) * np.random.default_rng(0).integers(1, 5, x.shape)
+    brute = np.array([[np.minimum(a, b).sum() / max(np.maximum(a, b).sum(), 1.0)
+                       for b in counts] for a in counts])
+    np.testing.assert_allclose(gram(p, jnp.asarray(counts, jnp.float32)), brute,
+                               atol=1e-6)
 
 
 @pytest.mark.parametrize("kind", KINDS)

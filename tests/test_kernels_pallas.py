@@ -194,30 +194,40 @@ def test_gram_matvec_grad_through_jitter():
 
 
 # ---------------------------------------------------------------------------
-# Backend selection + tanimoto fallback
+# Backend selection: every kind, tanimoto included, has a fused kernel
 # ---------------------------------------------------------------------------
 
 
 def test_resolve_backend_auto_and_tanimoto():
-    # auto: pallas on TPU only; CPU test container resolves to chunked
-    assert resolve_backend("auto", "se") in ("pallas", "chunked")
-    assert resolve_backend("auto", "tanimoto") == "chunked"  # silent fallback
+    # auto: pallas on TPU only; CPU test container resolves to chunked, for
+    # tanimoto as for the stationary kinds — nothing is rerouted by kind
+    on_tpu = jax.default_backend() == "tpu"
+    for kind in ("se", "tanimoto"):
+        assert resolve_backend("auto", kind) == ("pallas" if on_tpu else "chunked")
+        assert resolve_backend("pallas", kind) == "pallas"
     assert resolve_backend("chunked", "tanimoto") == "chunked"
-    with pytest.raises(ValueError, match="tanimoto"):
-        resolve_backend("pallas", "tanimoto")
+    with pytest.raises(ValueError, match="no fused Pallas matvec"):
+        resolve_backend("pallas", "cosine")
     with pytest.raises(ValueError, match="unknown backend"):
         resolve_backend("cuda", "se")
 
 
 def test_tanimoto_pallas_raises_auto_falls_back():
-    key = jax.random.PRNGKey(8)
-    x = jnp.abs(jax.random.normal(key, (50, 6)))
-    v = jax.random.normal(jax.random.fold_in(key, 1), (50, 2))
-    p = make_params("tanimoto", lengthscale=1.0, signal=1.2, d=6)
-    with pytest.raises(ValueError, match="tanimoto"):
-        gram_mv(p, x, v, backend="pallas", interpret=True)
-    out = gram_mv(p, x, v, backend="auto")  # falls back to chunked
-    np.testing.assert_allclose(out, gram(p, x) @ v, rtol=1e-5, atol=1e-5)
+    """The fused Tanimoto matvec is MinMax on counts (interpret mode here);
+    ``auto`` gives the same numbers, and values it cannot take exactly read
+    NaN, never an approximation."""
+    rng = np.random.default_rng(8)
+    x = ((rng.random((50, 24)) < 0.3) * rng.integers(1, 5, (50, 24))).astype(np.float32)
+    v = rng.normal(size=(50, 2)).astype(np.float32)
+    p = make_params("tanimoto", signal=1.2, noise=0.3)
+    want = np.asarray(gram(p, x)) @ v
+    out = gram_mv(p, x, v, backend="pallas", block=16, interpret=True)
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(gram_mv(p, x, v, backend="auto"), want,
+                               rtol=1e-5, atol=1e-5)
+    x[7, 3] = 5.0  # above MAX_COUNT
+    out = np.asarray(gram_mv(p, x, v, backend="pallas", block=16, interpret=True))
+    assert np.isnan(out).all()  # an invalid row of the symmetric operator reaches every output
 
 
 @pytest.mark.parametrize("backend", ["chunked", "dense", "pallas"])

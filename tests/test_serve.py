@@ -224,6 +224,30 @@ def test_predict_padding_matches_direct_evaluation(small_problem):
     np.testing.assert_allclose(np.asarray(var), np.asarray(var_ref), atol=1e-5)
 
 
+def test_predict_split_rows_gives_large_queries_their_own_pass(small_problem):
+    """A query at or above ``predict_split_rows`` is computed in a pass of its
+    own bucket: the batch's small queries are not padded to its size, and
+    every answer still equals direct evaluation, in submit order."""
+    eng = _engine(small_problem, predict_split_rows=32)
+    x = small_problem["x"]
+    queries = [x[:3] + 0.01, x[:40] + 0.02, x[:5] + 0.03]
+    handles = [eng.predict(q) for q in queries]
+    before = eng.stats()
+    assert len(eng.step()) == 3
+    after = eng.stats()
+    # passes: the two small queries in 2 blocks of 16 rows, the large one in 64
+    assert after["feature_rows"] - before["feature_rows"] == 2 * 16 + 64
+    assert after["predict_padded_rows"] - before["predict_padded_rows"] == 96 - 48
+    for q, h in zip(queries, handles):
+        mean_ref, var_ref = eng.state.post.sample_mean_and_var(q)
+        np.testing.assert_allclose(np.asarray(h.result().value["mean"]),
+                                   np.asarray(mean_ref), atol=1e-5)
+        np.testing.assert_allclose(np.asarray(h.result().value["var"]),
+                                   np.asarray(var_ref), atol=1e-5)
+    with pytest.raises(ValueError, match="predict_split_rows"):
+        _engine(small_problem, predict_split_rows=0)
+
+
 def test_fifo_completion_order_and_batching(small_problem):
     eng = _engine(small_problem)
     xs = small_problem["x"][:3]
