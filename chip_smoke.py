@@ -130,9 +130,12 @@ def column_rel_err(got, ref):
 
 
 def backend_counters() -> dict:
-    from repro.kernels.ops import FEATURE_TRACE_COUNTS, MATVEC_TRACE_COUNTS
+    from repro.kernels.ops import (
+        FEATURE_TRACE_COUNTS, GRAM_TILING_TRACE_COUNTS, MATVEC_TRACE_COUNTS,
+    )
 
     return dict(gram=dict(MATVEC_TRACE_COUNTS),
+                tiling=dict(GRAM_TILING_TRACE_COUNTS),
                 features=dict(FEATURE_TRACE_COUNTS))
 
 
@@ -208,6 +211,8 @@ def serving_phase(x, y, x_new, y_new, params, *, seed: int,
           f"Gram matvecs fell back off Pallas: {counters['gram']}")
     check(counters["features"]["features"] == 0,
           f"feature matrix materialised: {counters['features']}")
+    check(counters["tiling"]["symmetric"] > 0,
+          f"no solve matvec took the symmetric grid: {counters['tiling']}")
 
     with span("reference"):
         idx = jax.random.choice(jax.random.PRNGKey(seed + 2), fit.n,

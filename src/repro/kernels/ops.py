@@ -35,6 +35,10 @@ the jitter likewise for ``tanimoto``).
 matvecs each backend dispatched (counted when the op is staged, i.e. per trace or
 eager call) — used by tests and benchmarks to prove the hot paths never silently
 fall back (see tests/test_backends_and_counts.py, tests/test_features.py).
+``GRAM_TILING_TRACE_COUNTS`` splits the fused stationary Gram matvecs by grid:
+``K(x, x) @ v`` takes the symmetric grid when it spans more than one block and
+its resident accumulator fits the kernel's VMEM budget
+(``gram_matvec.symmetric_fits``), everything else the square one.
 """
 from __future__ import annotations
 
@@ -47,6 +51,7 @@ from .gram_matvec import (
     TILE_PRECISIONS,
     gram_matvec_fused,
     gram_rows_pair_fused,
+    symmetric_fits,
 )
 from .rff_matvec import rff_matvec_fused, rff_pair_fused, rff_t_matvec_fused
 from .tanimoto import ABSENT, rf_signs, tanimoto_matvec_fused, tanimoto_rf_pallas
@@ -82,6 +87,12 @@ FEATURE_BACKENDS = ("auto", "pallas", "features")
 # hot path — see tests/test_backends_and_counts.py.
 MATVEC_TRACE_COUNTS = {"pallas": 0, "chunked": 0, "dense": 0}
 
+# grid -> number of fused stationary Gram matvecs staged on it: "symmetric"
+# (K(x, x) within the kernel's VMEM budget, each off-diagonal tile built once
+# and contracted both ways) or "square" (a cross-Gram, one block, or over the
+# budget).
+GRAM_TILING_TRACE_COUNTS = {"symmetric": 0, "square": 0}
+
 # backend -> number of feature matvecs (Φw / Φᵀu) dispatched. A solve whose
 # "features" count stays zero provably never materialised an (n, 2m) feature
 # matrix — the acceptance check for the fused SGD regulariser.
@@ -91,6 +102,8 @@ FEATURE_TRACE_COUNTS = {"pallas": 0, "features": 0}
 def reset_matvec_trace_counts() -> None:
     for k in MATVEC_TRACE_COUNTS:
         MATVEC_TRACE_COUNTS[k] = 0
+    for k in GRAM_TILING_TRACE_COUNTS:
+        GRAM_TILING_TRACE_COUNTS[k] = 0
 
 
 def reset_feature_trace_counts() -> None:
@@ -190,8 +203,11 @@ def _pallas_gram_mv(params, x, v2, z, block, interpret, precision="fp32"):
     xp = _pad_rows(xs, block)
     zp = xp if zs is None else _pad_rows(zs, block)
     vp = _pad_rows(v2, block)
+    symmetric = zs is None and symmetric_fits(xp.shape[0], vp.shape[1], block)
+    GRAM_TILING_TRACE_COUNTS["symmetric" if symmetric else "square"] += 1
     out = gram_matvec_fused(
-        params.kind, block, block, bool(interpret), precision, xp, zp, vp
+        params.kind, block, block, bool(interpret), precision, symmetric,
+        xp, zp, vp,
     )
     return params.signal * out[:n]
 

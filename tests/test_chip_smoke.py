@@ -75,6 +75,7 @@ def test_serving_phase_tiny(smoke, pallas_auto):
     out = smoke.serving_phase(x, y, x[:4] + 0.05, y[:4], params, seed=0,
                               num_samples=4, requests=16, parity_rows=32)
     assert out["counters"]["gram"]["pallas"] > 0
+    assert out["counters"]["tiling"]["symmetric"] > 0  # the shared CG solves
     assert out["stats"]["warm_hits"] > 0
     assert out["parity"] <= smoke.PARITY_BOUND
     assert out["stats"]["phases"]["step"]["calls"] == out["stats"]["steps"]

@@ -5,8 +5,17 @@ import numpy as np
 import pytest
 
 from repro.core.kernels_fn import make_params
-from repro.kernels.ops import flash_attention, gram_matvec, rff_matvec
+from repro.kernels.gram_matvec import PALLAS_KINDS, symmetric_fits
+from repro.kernels.ops import (
+    GRAM_TILING_TRACE_COUNTS, flash_attention, gram_matvec, gram_mv,
+    reset_matvec_trace_counts, rff_matvec,
+)
 from repro.kernels.ref import flash_attention_ref, gram_matvec_ref, rff_matvec_ref
+
+
+def _rel_err(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    return np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1e-30)
 
 
 @pytest.mark.parametrize("kind", ["se", "matern12", "matern32", "matern52"])
@@ -21,6 +30,64 @@ def test_gram_matvec_kinds_shapes(kind, n, m, s):
     ref = gram_matvec_ref(x / p.lengthscale, z / p.lengthscale, v,
                           kind=kind, signal=float(p.signal))
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("kind", PALLAS_KINDS)
+@pytest.mark.parametrize("nb", [1, 2, 3, 4])
+@pytest.mark.parametrize("s", [1, 17])
+@pytest.mark.parametrize("precision", ["fp32", "bf16"])
+def test_gram_matvec_symmetric_tiling(kind, nb, s, precision):
+    """K(x, x) @ v on the symmetric grid: odd and even block counts (the
+    half-offset pair), n not a block multiple; against the dense reference and
+    against the square grid of the same operator (z given). One block has no
+    pair to share and stays on the square grid."""
+    n = 64 * nb - 13
+    key = jax.random.PRNGKey(100 * nb + s)
+    x = jax.random.normal(key, (n, 4))
+    v = jax.random.normal(jax.random.fold_in(key, 1), (n, s))
+    p = make_params(kind, lengthscale=0.8, signal=1.4, d=4)
+    kw = dict(block=64, interpret=True, precision=precision)
+    reset_matvec_trace_counts()
+    out = gram_matvec(p, x, v, **kw)
+    sym = int(nb > 1)
+    assert GRAM_TILING_TRACE_COUNTS == {"symmetric": sym, "square": 1 - sym}
+    square = gram_matvec(p, x, v, z=x, **kw)
+    assert GRAM_TILING_TRACE_COUNTS == {"symmetric": sym, "square": 2 - sym}
+    ref = gram_matvec_ref(x / p.lengthscale, x / p.lengthscale, v,
+                          kind=kind, signal=float(p.signal))
+    # Matérn-1/2 takes √d² on the diagonal, where the matmul identity's
+    # roundoff (d² ~ 1e-7 rather than 0) differs between kernel and reference
+    tol = {"fp32": 2e-3 if kind == "matern12" else 2e-4, "bf16": 2e-2}[precision]
+    assert _rel_err(out, ref) < tol
+    assert _rel_err(out, square) < 1e-5
+
+
+def test_gram_tiling_engagement_and_counter():
+    """The symmetric grid is taken for K(x, x) within the VMEM budget only:
+    a cross-Gram and an over-budget symmetric shape take the square grid."""
+    p = make_params("matern32", lengthscale=1.0, d=3)
+    x = jax.random.normal(jax.random.PRNGKey(0), (96, 3))
+    v = jnp.ones((96, 2))
+    reset_matvec_trace_counts()
+    gram_mv(p, x, v, backend="pallas", block=64, interpret=True)
+    assert GRAM_TILING_TRACE_COUNTS == {"symmetric": 1, "square": 0}
+    gram_mv(p, x, v, z=x[:40], backend="pallas", block=64, interpret=True)
+    assert GRAM_TILING_TRACE_COUNTS == {"symmetric": 1, "square": 1}
+    gram_mv(p, x[:64], v[:64], backend="pallas", block=64, interpret=True)
+    assert GRAM_TILING_TRACE_COUNTS == {"symmetric": 1, "square": 2}  # one block
+    n_big, s_big, block = 200_000, 64, 512
+    assert not symmetric_fits(n_big, s_big, block)
+    assert symmetric_fits(15_360, s_big, block)  # the pol solve
+    big = jax.eval_shape(
+        lambda x, v: gram_mv(p, x, v, backend="pallas", block=block,
+                             interpret=True),
+        jax.ShapeDtypeStruct((n_big, 3), jnp.float32),
+        jax.ShapeDtypeStruct((n_big, s_big), jnp.float32),
+    )
+    assert big.shape == (n_big, s_big)
+    assert GRAM_TILING_TRACE_COUNTS == {"symmetric": 1, "square": 3}
+    reset_matvec_trace_counts()
+    assert GRAM_TILING_TRACE_COUNTS == {"symmetric": 0, "square": 0}
 
 
 def test_gram_matvec_jitter_square():
@@ -101,12 +168,7 @@ def test_gram_matvec_bf16_inputs():
 # ---------------------------------------------------------------------------
 
 from repro.core.kernels_fn import gram  # noqa: E402
-from repro.kernels.ops import gram_mv, gram_rows_matvec, resolve_backend  # noqa: E402
-
-
-def _rel_err(a, b):
-    a, b = np.asarray(a), np.asarray(b)
-    return np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1e-30)
+from repro.kernels.ops import gram_rows_matvec, resolve_backend  # noqa: E402
 
 
 @pytest.mark.parametrize("kind", ["se", "matern12", "matern32", "matern52"])
@@ -149,6 +211,9 @@ def test_gram_matvec_vjp_symmetric(kind):
     def dense(p, x):
         return jnp.sum(u * (gram(p, x) @ v))
 
+    reset_matvec_trace_counts()
+    assert _rel_err(fused(p, x), dense(p, x)) < 1e-5  # the symmetric forward
+    assert GRAM_TILING_TRACE_COUNTS["symmetric"] == 1
     gf = jax.grad(fused, argnums=(0, 1))(p, x)
     gd = jax.grad(dense, argnums=(0, 1))(p, x)
     assert _rel_err(gf[0].log_lengthscale, gd[0].log_lengthscale) < 1e-4
