@@ -12,21 +12,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.kernels_fn import make_params
-from repro.core.operators import Gram
 from repro.core.pathwise import posterior_functions
-from repro.core.solvers.spec import CG, SDD, SGD, solve
+from repro.core.solvers.spec import CG, SDD, SGD
 from repro.core.svgp import sgpr
 from repro.data.pipeline import regression_dataset
 
 from .common import Report, nll_gaussian, rmse, timed
-
-#: step budget of the dedicated per-iteration probe — fixed (and small) in both
-#: smoke and default modes so the ``us_per_iter`` rows are comparable across
-#: runs and against the committed baseline regardless of ``num_steps``.
-PROBE_STEPS = 200
-
-#: RHS column width of the probe — num_samples + 1, the pathwise multi-RHS batch.
-PROBE_COLS = 17
 
 
 def _mv_equiv(spec, n: int) -> float:
@@ -43,20 +34,6 @@ def _mv_equiv(spec, n: int) -> float:
     else:
         per_step = p / n
     return round(1.0 + steps * per_step, 1)
-
-
-def _per_iter_us(params, x, spec, key) -> int:
-    """Compiled per-iteration wall time (microseconds) of a stochastic solve.
-
-    A dedicated multi-RHS solve at the probe's fixed step budget, run twice —
-    the first call compiles, the second is timed — so the number is the hot
-    scan's per-step cost, independent of compile time and of ``num_steps``."""
-    op = Gram(x=x, params=params)
-    b = jax.random.normal(key, (x.shape[0], PROBE_COLS))
-    probe = dataclasses.replace(spec, num_steps=PROBE_STEPS)
-    solve(op, b, probe, key=key)  # compile + warm up
-    _, dt = timed(solve, op, b, probe, key=key)
-    return int(round(dt / PROBE_STEPS * 1e6))
 
 
 def run(report: Report, full: bool = False, smoke: bool = False):
@@ -105,12 +82,6 @@ def run(report: Report, full: bool = False, smoke: bool = False):
                        rmse=rmse(mu, yt), nll=nll_gaussian(yt, mu, var),
                        seconds=round(dt, 2), iters=int(info.iterations),
                        matvecs=int(info.matvecs), **extra)
-            if method in ("SGD", "SDD"):
-                # wall-clock per iteration — the raw-speed number this table is
-                # gated on (check_matvecs --skip-walltime to bypass on noisy
-                # runners); measured by a dedicated compiled probe, not dt/steps
-                us = _per_iter_us(p, x, spec, jax.random.PRNGKey(3))
-                report.add("solvers-periter", method, name, us_per_iter=us)
         # SVGP baseline (collapsed SGPR with m inducing points)
         z = x[:: max(1, n // 512)][:512]
         post, dt = timed(sgpr, p, x, y, z)

@@ -3,8 +3,10 @@
 
 Each bench additionally emits a machine-readable ``BENCH_<name>.json`` into
 ``--outdir`` (default ``results/``): wall time, per-row metrics (RMSE/NLL,
-solver iterations, full-Gram-matvec counts where the bench reports them), so the
-performance trajectory is tracked across PRs instead of living in scrollback.
+solver iterations, full-Gram-matvec counts where the bench reports them), so
+the counts ``check_matvecs`` gates are committed rather than left in
+scrollback. These are CPU runs: speed is measured on the chip
+(``benchmarks/chip/``).
 """
 from __future__ import annotations
 
@@ -28,10 +30,9 @@ BENCHES = [
     "bench_kronecker",  # Chapter 6
     "bench_thompson",  # Figures 3.7 / 4.4
     "bench_serve",  # serving engine: continuous batching + warm starts
-    "bench_robust",  # guardrail overhead + escalation-ladder recovery
+    "bench_robust",  # guardrail matvec parity + escalation-ladder recovery
     "bench_distributed",  # ring vs gather comm strategies (4-device subprocess)
     "bench_molecules",  # Table 4.2
-    "bench_gram_kernel",  # Pallas tile sweep
     "bench_roofline",  # §Roofline (reads dry-run JSONL)
 ]
 

@@ -276,7 +276,8 @@ class Gram(_InstrumentedOp):
     (``CG(backend="pallas")``), and likewise ``precision`` — ``"fp32"``
     (default) or ``"bf16"`` tile contractions with fp32 accumulation (see
     kernels/ops.py PRECISIONS). ``block`` is the Pallas tile size; the
-    ``"auto"`` default resolves per shape at trace time (kernels/autotune.py).
+    ``"auto"`` default resolves per shape at trace time
+    (kernels/ops.py ``stationary_block``).
     ``instrument=True`` counts executed matvecs via ``matvec_counts()``
     (tests/benchmarks; adds a host callback per matvec).
     """

@@ -8,8 +8,9 @@ instead of O(n·m); arithmetic intensity rises from ~0.5 flop/byte (materialised
 memory-bound) to ~bn·s/(d+s) — compute-bound for the solver's multi-RHS batches.
 
 Grid: (rows n/bm, cols m/bn), cols innermost ("arbitrary") so the output tile stays
-resident in VMEM across the full accumulation. Block shapes default to 256×256
-(MXU-aligned multiples of 128; VMEM footprint ≈ bm·bn·4 + (bm+bn)·(d+s)·4 ≈ 0.5 MB).
+resident in VMEM across the full accumulation. Blocks are MXU-aligned multiples
+of 128 chosen by ops.py (``stationary_block``: 512 once the operand spans it);
+one step's VMEM footprint ≈ bm·bn·4 + (bm+bn)·(d+s)·4.
 
 For the symmetric operator K(x, x) @ v the forward can take a symmetric grid
 instead (``symmetric=True``, chosen by ops.py from the shapes): each unordered

@@ -45,7 +45,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .autotune import resolve_block as _autotune_block
 from .gram_matvec import (
     PALLAS_KINDS,
     TILE_PRECISIONS,
@@ -68,6 +67,12 @@ FUSED_KINDS = PALLAS_KINDS + ("tanimoto",)
 TANIMOTO_BLOCK = 512
 #: Tanimoto random features: rows and hashes per grid step
 TANIMOTO_RF_BLOCK = 256
+
+#: VMEM one step of a stationary Gram or RFF tile may take (half of the
+#: core's 16 MiB, leaving room for double buffering) ...
+_TILE_VMEM = 8 * 2 ** 20
+#: ... at this many right-hand sides (the pathwise batch, num_samples + 1 ≈ 16)
+_TILE_RHS = 16
 
 #: Tile/contration precisions (re-exported from gram_matvec): ``"fp32"``
 #: everywhere by default; ``"bf16"`` casts contraction operands to bfloat16
@@ -147,14 +152,32 @@ def _check_precision(precision: str) -> str:
     return precision
 
 
+def stationary_block(family: str, rows: int, d: int, precision: str) -> int:
+    """The ``block="auto"`` tile of the stationary Gram (``family="gram"``)
+    and RFF (``"rff"``) kernels over an operand of ``rows`` rows.
+
+    512 from 512 rows; below that 256 from 256 rows if one 256-tile step fits
+    ``_TILE_VMEM``; else 128. Only the 128 floor ever out-pads the operand.
+    A step holds the two point tiles and the RHS tile (both weight
+    halves for ``rff``) at the tile precision, and the fp32 (b, b) tile and
+    (b, s) accumulator.
+    """
+    if rows >= 512:
+        return 512
+    b, s, el = 256, _TILE_RHS, 2 if precision == "bf16" else 4
+    rhs = 2 * b * s if family == "rff" else b * s
+    step = el * (2 * b * d + rhs) + 4 * (b * b + b * s)
+    return 256 if rows >= 256 and step <= _TILE_VMEM else 128
+
+
 def _resolve_block(block, family: str, n: int, d: int, precision: str) -> int:
-    """``"auto"`` → the autotuned/heuristic tile size; ints pass through.
+    """``"auto"`` → :func:`stationary_block`; ints pass through.
 
     Runs at trace time on static shapes, so the result is a plain Python int
     and a repeated call with the same shapes re-traces nothing.
     """
     if block == "auto":
-        return _autotune_block(family, n, d, precision=precision)
+        return stationary_block(family, n, d, precision)
     return int(block)
 
 

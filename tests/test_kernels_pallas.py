@@ -8,7 +8,7 @@ from repro.core.kernels_fn import make_params
 from repro.kernels.gram_matvec import PALLAS_KINDS, symmetric_fits
 from repro.kernels.ops import (
     GRAM_TILING_TRACE_COUNTS, flash_attention, gram_matvec, gram_mv,
-    reset_matvec_trace_counts, rff_matvec,
+    reset_matvec_trace_counts, rff_matvec, stationary_block,
 )
 from repro.kernels.ref import flash_attention_ref, gram_matvec_ref, rff_matvec_ref
 
@@ -88,6 +88,65 @@ def test_gram_tiling_engagement_and_counter():
     assert GRAM_TILING_TRACE_COUNTS == {"symmetric": 1, "square": 3}
     reset_matvec_trace_counts()
     assert GRAM_TILING_TRACE_COUNTS == {"symmetric": 0, "square": 0}
+
+
+@pytest.mark.parametrize("family,precision,d,rows,block", [
+    ("gram", "fp32", 26, 15_000, 512),
+    ("gram", "fp32", 26, 512, 512),
+    ("gram", "fp32", 26, 511, 256),  # never pads past the tile it fills
+    ("gram", "fp32", 26, 256, 256),
+    ("gram", "fp32", 26, 255, 128),
+    ("gram", "fp32", 26, 128, 128),
+    ("gram", "fp32", 26, 16, 128),
+    ("rff", "fp32", 26, 1, 128),
+    ("rff", "fp32", 26, 15_000, 512),
+    ("rff", "bf16", 26, 300, 256),
+    ("gram", "fp32", 4096, 300, 128),  # a 256 step is over the VMEM budget
+    ("gram", "bf16", 4096, 300, 256),  # bf16 operand tiles fit it
+    ("gram", "fp32", 4096, 512, 512),
+    ("rff", "fp32", 8192, 600, 512),
+])
+def test_stationary_block_rule(family, precision, d, rows, block):
+    got = stationary_block(family, rows, d, precision)
+    assert got == block and type(got) is int
+
+
+def test_auto_block_matvec_matches_explicit():
+    key = jax.random.PRNGKey(0)
+    x = jax.random.normal(key, (192, 3))
+    v = jax.random.normal(jax.random.fold_in(key, 1), (192, 2))
+    p = make_params("se", lengthscale=1.0, signal=1.0, d=3)
+    auto = gram_matvec(p, x, v, block="auto", interpret=True)
+    explicit = gram_matvec(
+        p, x, v, block=stationary_block("gram", 192, 3, "fp32"), interpret=True
+    )
+    np.testing.assert_allclose(auto, explicit, rtol=1e-6, atol=1e-6)
+
+
+def test_auto_block_does_not_retrace():
+    """The resolved block is a static Python int derived from static shapes, so
+    value-only changes reuse the compiled solve: the rule never smuggles a
+    traced quantity into the pallas_call config."""
+    p = make_params("se", lengthscale=1.0, signal=1.0, d=3)
+    traces = []
+
+    @jax.jit
+    def mv(x, v):
+        traces.append(1)
+        return gram_mv(p, x, v, backend="pallas", block="auto", interpret=True)
+
+    key = jax.random.PRNGKey(1)
+    x1 = jax.random.normal(key, (160, 3))
+    v1 = jax.random.normal(jax.random.fold_in(key, 1), (160, 2))
+    mv(x1, v1)
+    mv(x1 + 1.0, v1 * 2.0)  # same shapes, new values: no retrace
+    assert len(traces) == 1, "block='auto' retraced on value-only changes"
+    # a different n is a shape change and legitimately retraces (and may
+    # resolve a different block, still statically)
+    x2 = jnp.concatenate([x1, x1])
+    v2 = jnp.concatenate([v1, v1])
+    mv(x2, v2)
+    assert len(traces) == 2
 
 
 def test_gram_matvec_jitter_square():

@@ -20,11 +20,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import autotune
 from repro.kernels.gram_matvec import (
     gram_matvec_bwd_pallas, gram_matvec_pallas, gram_rows_pair_pallas,
     symmetric_fits,
 )
+from repro.kernels.ops import stationary_block
 from repro.kernels.rff_matvec import (
     rff_matvec_pallas, rff_pair_pallas, rff_t_matvec_pallas,
 )
@@ -62,8 +62,8 @@ def one_chip(topo):
 def blocks():
     """The ``block="auto"`` tiles the serving path resolves at the pol width,
     and the padded n they give (15,360)."""
-    bg = autotune.resolve_block("gram", N_POL, D_POL)
-    br = autotune.resolve_block("rff", N_POL, D_POL)
+    bg = stationary_block("gram", N_POL, D_POL, "fp32")
+    br = stationary_block("rff", N_POL, D_POL, "fp32")
     n_pad = -(-N_POL // bg) * bg
     assert n_pad == 15_360 and n_pad % br == 0, (bg, br, n_pad)
     return dict(gram=bg, rff=br, n=n_pad)
