@@ -78,6 +78,20 @@ from .state import (
 )
 
 
+@jax.jit
+def _predict_pass(post: PosteriorFunctions, blocks: jax.Array):
+    """One predict pass as one program: the prior's feature map on the
+    flattened ``(nblk, rows, d)`` blocks, then the fused mean/variance pass
+    (:meth:`~repro.core.pathwise.PosteriorFunctions.blocked_mean_and_var`);
+    returns the ``(nblk, rows)`` mean and variance. Compiled once per block
+    shape and posterior pytree structure, so the kernel kind and the feature
+    map follow from the traced types."""
+    nblk, rows, d = blocks.shape
+    with jax.named_scope("gp.features"):
+        f_prior = post.prior(blocks.reshape(nblk * rows, d))
+    return post.blocked_mean_and_var(blocks, f_prior)
+
+
 class EngineOverloaded(RuntimeError):
     """Backpressure signal: the queue is past ``max_queue_depth`` and the
     overload policy rejected this submit. Callers back off and retry."""
@@ -293,7 +307,8 @@ class GPEngine:
                 raise ValueError(f"{kind!r} requests need a query block xs of shape (m, d)")
             if tanimoto:
                 check_counts(xs)
-            xs = jnp.atleast_2d(jnp.asarray(xs))
+            # the block stays on the host until its pass moves it, once
+            xs = np.atleast_2d(np.asarray(xs, dtype=self.state.x.dtype))
             if xs.shape[1] != self.state.x.shape[1]:
                 raise ValueError(
                     f"query block has feature dimension {xs.shape[1]}, "
@@ -520,6 +535,12 @@ class GPEngine:
 
     # --------------------------------------------------------------- execution
 
+    def _upload(self, block: np.ndarray) -> jax.Array:
+        """Move one host query block to the state's device, counting its
+        bytes in ``query_bytes_to_device``."""
+        self._stats.query_bytes_to_device += block.nbytes
+        return jax.device_put(block, next(iter(self.state.x.devices())))
+
     def _predict_passes(self, requests) -> List[List[int]]:
         """Indices of ``requests`` per predict pass: one pass for every request
         below ``predict_split_rows`` and one per row bucket at or above it."""
@@ -533,12 +554,15 @@ class GPEngine:
     def _execute_predict(self, plan: BatchPlan):
         """Fused row-batched mean/variance passes over cached state: one per
         group of ``_predict_passes``, each block padded to its pass's largest
-        request.
+        request. A pass fills one host ``(nblk, rows, d)`` array from the
+        requests' host blocks, moves it to the device once and dispatches
+        :func:`_predict_pass` once; the passes of a batch are dispatched back
+        to back and waited for together in ``gp.block``.
 
         Spans, per pass: ``gp.predict`` (``kernel``, ``rows`` the requests'
-        rows, ``bucket_rows`` each block's padded rows) around it,
-        ``gp.features`` (``kind``, ``rows``) around the prior's evaluation on
-        the blocks."""
+        rows, ``bucket_rows`` each block's padded rows) around it; the
+        prior's feature map is the named scope ``gp.features`` inside the
+        program."""
         state = self.state
         d = state.x.shape[1]
         values: list = [None] * len(plan.requests)
@@ -552,15 +576,12 @@ class GPEngine:
                                   rows=real_rows, bucket_rows=rows):
                 blocks = np.zeros((nblk, rows, d), dtype=state.x.dtype)
                 for i, req in enumerate(reqs):
-                    blocks[i, : req.num_rows] = np.asarray(req.xs)
-                blocks = jnp.asarray(blocks)
-                with self._stats.span("features", kind=state.params.kind,
-                                      rows=nblk * rows):
-                    f_prior = state.post.prior(blocks.reshape(nblk * rows, d))
-                mean, var = state.post.blocked_mean_and_var(blocks, f_prior)
+                    blocks[i, : req.num_rows] = req.xs
+                mean, var = _predict_pass(state.post, self._upload(blocks))
                 for i, (j, r) in enumerate(zip(idx, reqs)):
                     values[j] = {"mean": mean[i, : r.num_rows],
                                  "var": var[i, : r.num_rows]}
+            self._stats.predict_passes += 1
             self._stats.predict_rows += real_rows
             self._stats.predict_padded_rows += nblk * rows - real_rows
             self._stats.feature_rows += nblk * rows
@@ -694,9 +715,11 @@ class GPEngine:
                     row_offsets.append(r_total)
                     r_total += req.num_rows
                 rbucket = bucket(r_total, self.row_bucket_min)
-                xs_all = jnp.concatenate([req.xs for req, _ in sample_at], axis=0)
-                xs_pad = jnp.pad(xs_all, ((0, rbucket - r_total), (0, 0)))
-                vals = state.post.sample_paths(xs_pad, w_cat, res.solution)
+                xs_all = np.concatenate([req.xs for req, _ in sample_at], axis=0)
+                xs_pad = np.pad(xs_all, ((0, rbucket - r_total), (0, 0)))
+                vals = state.post.sample_paths(
+                    self._upload(xs_pad), w_cat, res.solution
+                )
                 self._stats.feature_rows += rbucket
                 for (req, lo), ro in zip(sample_at, row_offsets):
                     values_by_id[req.id] = {
@@ -712,7 +735,7 @@ class GPEngine:
                     self._stats.feature_rows += req.num_rows
                     values_by_id[req.id] = {
                         "samples": state.post.sample_paths(
-                            req.xs, w_req, rescued[req.id]
+                            self._upload(req.xs), w_req, rescued[req.id]
                         )
                     }
 

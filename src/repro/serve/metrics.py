@@ -225,6 +225,10 @@ class EngineStats:
     last_refit_rel_residual: float = 0.0  # latest update's certified drift
     predict_rows: int = 0
     predict_padded_rows: int = 0
+    predict_passes: int = 0  # one program dispatch each
+    # bytes of query rows moved host to device (predict and sample blocks
+    # with their padding; submit moves none)
+    query_bytes_to_device: int = 0
     # rows the engine passed to the prior's feature map (fit, solve
     # right-hand sides, predict and sample blocks with their padding)
     feature_rows: int = 0
@@ -327,6 +331,8 @@ class EngineStats:
             "last_refit_rel_residual": self.last_refit_rel_residual,
             "predict_rows": self.predict_rows,
             "predict_padded_rows": self.predict_padded_rows,
+            "predict_passes": self.predict_passes,
+            "query_bytes_to_device": self.query_bytes_to_device,
             "feature_rows": self.feature_rows,
             "deadline_misses": self.deadline_misses,
             "shed": self.shed,

@@ -25,7 +25,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, Optional
 
-import jax
+import numpy as np
 
 PREDICT = "predict"
 SAMPLE = "sample"
@@ -52,7 +52,9 @@ class Request:
 
     id: int
     kind: str
-    xs: Optional[jax.Array]  # (m, d) query block; None for thompson_step
+    # (m, d) query block, a host array of the state's dtype until its pass
+    # moves it to the device; None for thompson_step
+    xs: Optional[np.ndarray]
     num_samples: int  # RHS columns this request contributes (solve kinds)
     seed: int
     arrival: float  # engine clock() at submit

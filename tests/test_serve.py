@@ -20,6 +20,7 @@ from repro.core.kernels_fn import make_params
 from repro.core.operators import Gram
 from repro.core.rff import PriorSamples
 from repro.core.solvers.spec import AP, CG, SDD, SGD, solve, solve_batched
+from repro.kernels.ops import MATVEC_TRACE_COUNTS
 from repro.serve import (
     FIFOScheduler,
     GPEngine,
@@ -30,6 +31,7 @@ from repro.serve import (
     percentile,
     update_state_lowrank,
 )
+from repro.serve.engine import _predict_pass
 
 
 @pytest.fixture(scope="module")
@@ -246,6 +248,72 @@ def test_predict_split_rows_gives_large_queries_their_own_pass(small_problem):
                                    np.asarray(var_ref), atol=1e-5)
     with pytest.raises(ValueError, match="predict_split_rows"):
         _engine(small_problem, predict_split_rows=0)
+
+
+def _eager_pass(post, queries, nblk, rows):
+    """One predict pass evaluated eagerly, an operation at a time: the same
+    padded blocks through the prior and ``blocked_mean_and_var``."""
+    d = queries[0].shape[1]
+    blocks = np.zeros((nblk, rows, d), np.float32)
+    for i, q in enumerate(queries):
+        blocks[i, : len(q)] = q
+    blocks = jnp.asarray(blocks)
+    return post.blocked_mean_and_var(blocks, post.prior(blocks.reshape(-1, d)))
+
+
+def _rel(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+@pytest.mark.parametrize("split, passes", [
+    (None, [([0, 1, 2], 4, 64)]),  # one pass: 3 blocks bucketed to 4, of 64 rows
+    (32, [([0, 2], 2, 16), ([1], 1, 64)]),  # the 40-row query on its own
+])
+def test_compiled_predict_pass_matches_eager(small_problem, split, passes):
+    """A pass moves its padded block to the device once and dispatches one
+    compiled program, whose mean and variance equal the eager
+    ``blocked_mean_and_var`` on the same blocks; ``submit`` keeps each query
+    block on the host and moves nothing."""
+    eng = _engine(small_problem, predict_split_rows=split)
+    x = np.asarray(small_problem["x"])
+    d = x.shape[1]
+    queries = [x[:3] + 0.01, x[:40] + 0.02, x[:5] + 0.03]
+    before = eng.stats()
+    handles = [eng.predict(q) for q in queries]
+    assert all(type(h.request.xs) is np.ndarray for h in handles)
+    assert eng.stats()["query_bytes_to_device"] == before["query_bytes_to_device"]
+    eng.step()
+    after = eng.stats()
+    assert after["predict_passes"] - before["predict_passes"] == len(passes)
+    assert after["query_bytes_to_device"] - before["query_bytes_to_device"] == sum(
+        nblk * rows * d * 4 for _, nblk, rows in passes)
+    for idx, nblk, rows in passes:
+        mean, var = _eager_pass(eng.state.post, [queries[i] for i in idx], nblk, rows)
+        for b, i in enumerate(idx):
+            got, m = handles[i].result().value, len(queries[i])
+            assert _rel(got["mean"], mean[b, :m]) <= 1e-6
+            assert _rel(got["var"], var[b, :m]) <= 1e-6
+
+
+def test_predict_pass_traces_once_per_shape(small_problem):
+    """Steps whose passes share a bucketed shape trace the pass program once;
+    a new shape traces it once more."""
+    eng = _engine(small_problem)
+    x = np.asarray(small_problem["x"])
+    _predict_pass.clear_cache()
+
+    def traces(*rows):
+        before = sum(MATVEC_TRACE_COUNTS.values())
+        for r in rows:
+            eng.predict(x[:r] + 0.01)
+            eng.step()
+        return sum(MATVEC_TRACE_COUNTS.values()) - before
+
+    assert traces(5) == 1  # (1, 16, d)
+    assert traces(3, 9, 16, 5) == 0  # the same bucketed shape
+    assert traces(20) == 1  # (1, 32, d)
+    assert traces(17, 32) == 0
 
 
 def test_fifo_completion_order_and_batching(small_problem):

@@ -274,8 +274,11 @@ def test_engine_refuses_thompson_and_bad_counts_on_tanimoto(engine):
 
 
 def test_engine_predict_spans_and_feature_rows(engine, tmp_path):
-    """``gp.predict`` carries the kernel and row counts, ``gp.features`` the
-    map's kind and rows, and ``feature_rows`` counts the padded block."""
+    """``gp.predict`` carries the kernel and row counts, and ``feature_rows``
+    counts the padded block. The pass is one compiled program: its feature
+    map is a named scope inside it, so no ``gp.features`` host span opens
+    (that span is the fit's), and the pass moves its block to the device
+    once."""
     import glob
 
     from jax.profiler import ProfileData
@@ -289,7 +292,10 @@ def test_engine_predict_spans_and_feature_rows(engine, tmp_path):
     after = eng.stats()
     # one batch of two blocks, each padded to the row bucket of 20 (32)
     assert after["feature_rows"] - before["feature_rows"] == 2 * 32
-    assert after["phases"]["features"]["calls"] == before["phases"]["features"]["calls"] + 1
+    assert after["phases"]["features"]["calls"] == before["phases"]["features"]["calls"]
+    assert after["predict_passes"] - before["predict_passes"] == 1
+    assert after["query_bytes_to_device"] - before["query_bytes_to_device"] == \
+        2 * 32 * 64 * 4
     path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True))[-1]
     found = {}
     for plane in ProfileData.from_file(path).planes:
@@ -300,5 +306,43 @@ def test_engine_predict_spans_and_feature_rows(engine, tmp_path):
     assert found["gp.predict"]["kernel"] == "tanimoto"
     assert int(found["gp.predict"]["rows"]) == 25
     assert int(found["gp.predict"]["bucket_rows"]) == 32
-    assert found["gp.features"]["kind"] == "tanimoto"
-    assert int(found["gp.features"]["rows"]) == 64
+    assert "gp.features" not in found
+
+
+@pytest.mark.parametrize("split, passes", [
+    (None, [([0, 1, 2], 4, 64)]),
+    (32, [([0, 2], 2, 16), ([1], 1, 64)]),
+])
+def test_engine_compiled_predict_pass_matches_eager(engine, split, passes):
+    """The compiled Tanimoto pass (the fused matvec's and the Tanimoto
+    features' dispatch in one program) equals the eager
+    ``blocked_mean_and_var`` on the same blocks, on one pass and on a batch
+    split by ``predict_split_rows``; one device move of ``nblk * rows * d``
+    float32 per pass, none at ``submit``."""
+    eng, rng = engine
+    queries = [counts(rng, 3), counts(rng, 40), counts(rng, 5)]
+    before = eng.stats()
+    eng.predict_split_rows = split
+    try:
+        handles = [eng.predict(q) for q in queries]
+        assert all(type(h.request.xs) is np.ndarray for h in handles)
+        assert eng.stats()["query_bytes_to_device"] == before["query_bytes_to_device"]
+        eng.step()
+    finally:
+        eng.predict_split_rows = None
+    after = eng.stats()
+    assert after["predict_passes"] - before["predict_passes"] == len(passes)
+    assert after["query_bytes_to_device"] - before["query_bytes_to_device"] == sum(
+        nblk * rows * 64 * 4 for _, nblk, rows in passes)
+    post = eng.state.post
+    for idx, nblk, rows in passes:
+        blocks = np.zeros((nblk, rows, 64), np.float32)
+        for b, i in enumerate(idx):
+            blocks[b, : len(queries[i])] = queries[i]
+        blocks = jnp.asarray(blocks)
+        mean, var = post.blocked_mean_and_var(blocks, post.prior(blocks.reshape(-1, 64)))
+        for b, i in enumerate(idx):
+            got, m = handles[i].result().value, len(queries[i])
+            for name, want in (("mean", mean[b, :m]), ("var", var[b, :m])):
+                err = np.max(np.abs(np.asarray(got[name]) - np.asarray(want)))
+                assert err <= 1e-6 * np.max(np.abs(np.asarray(want))), name

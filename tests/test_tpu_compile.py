@@ -155,3 +155,56 @@ def test_rff_pair_compiles(one_chip, blocks):
         one_chip, (n, D_POL), (M_FEATURES, D_POL), (n, S),
     )
     assert "tpu_custom_call" in text
+
+
+def _posterior_shapes(kind, n, d, sharding, features=1024, samples=16):
+    """A served posterior's pytree (no solve record) as shapes on the
+    described chip: the engine's defaults of 1,024 features and 16 samples."""
+    from repro.core.kernels_fn import KernelParams, make_params
+    from repro.core.pathwise import PosteriorFunctions
+    from repro.core.rff import FourierFeatures, PriorSamples, TanimotoFeatures
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    p = make_params(kind, d=d)
+    params = KernelParams(
+        *(sds(jnp.shape(a)) for a in (p.log_lengthscale, p.log_signal, p.log_noise)),
+        kind=kind,
+    )
+    m = features
+    if kind == "tanimoto":
+        ff = TanimotoFeatures(r=sds((m, d)), c=sds((m, d)), beta=sds((m, d)),
+                              salt=sds((m,), jnp.uint32), table=sds((4, m, d)),
+                              signal=sds(()))
+    else:
+        ff = FourierFeatures(omega=sds((m // 2, d)), phase=sds((m // 2,)),
+                             signal=sds(()))
+    return PosteriorFunctions(
+        params=params, x=sds((n, d)), prior=PriorSamples(ff=ff, w=sds((m, samples))),
+        v_mean=sds((n,)), alpha=sds((n, samples)),
+    )
+
+
+@pytest.mark.parametrize("kind, n, d, rows", [
+    ("matern32", N_POL, D_POL, 128),  # pol: 16 predict blocks of 128 rows
+    ("tanimoto", 131_072, 1024, 64),  # dock: 16 hit lists of 64 molecules
+])
+def test_predict_pass_compiles(one_chip, monkeypatch, kind, n, d, rows):
+    """The engine's predict pass is one program holding both fused kernels,
+    the matvec and the prior's features, under the names the benchmark's
+    trace readers look for."""
+    import repro.kernels.ops as ops
+    from repro.serve.engine import _predict_pass
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)  # "auto": Mosaic kernels
+    try:
+        post = _posterior_shapes(kind, n, d, one_chip)
+        blocks = jax.ShapeDtypeStruct((16, rows, d), jnp.float32, sharding=one_chip)
+        text = _predict_pass.lower(post, blocks).compile().as_text()
+    finally:
+        _predict_pass.clear_cache()
+    names = (("tanimoto_matvec_pallas", "tanimoto_rf_pallas") if kind == "tanimoto"
+             else ("gram_matvec_pallas", "rff_matvec_pallas"))
+    for name in names:
+        assert re.search(rf"%{name}[\w.]* = \S+ custom-call\(", text), name
